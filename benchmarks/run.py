@@ -18,6 +18,9 @@ from .common import Csv
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (
         adaptive_replan,
         elastic_churn,

@@ -38,7 +38,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..kernels.ops import shard_map_compat
 from .schemes import (CodingScheme, chunk_bounds, commutes_elementwise,
                       decode_blocks, resolve_subset, source_of_piece)
 from .splitting import (ChainPlan, ConvSpec, SegmentSplitPlan, SplitPlan,
@@ -378,15 +377,13 @@ def coded_conv2d_sharded(
     parts = split_input(x, plan)  # (k, ...)
     coded_in = _encode_partitions(code, parts)  # (n, ...)
 
-    shard_map = shard_map_compat()
-
     @jax.jit
     def _run(coded_in, w):
         def worker(xi, w):
             # xi: (1, B, C, H, W_I^p) — this device's coded partition.
             return conv2d(xi[0], w, spec.stride)[None]
 
-        out = shard_map(
+        out = jax.shard_map(
             worker,
             mesh=mesh,
             in_specs=(P(axis), P()),

@@ -170,16 +170,12 @@ def coded_matmul_sharded(
     plan = plan_token_split(T, code.k)
     coded_in = _encode_tokens(code, x, plan)
 
-    from ..kernels.ops import shard_map_compat
-
-    shard_map = shard_map_compat()
-
     @jax.jit
     def _run(coded_in, w):
         def worker(xi, w):
             return jnp.einsum("ntd,df->ntf", xi, w)
 
-        return shard_map(
+        return jax.shard_map(
             worker, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(axis)
         )(coded_in, w)
 

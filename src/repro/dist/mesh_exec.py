@@ -50,7 +50,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..core.schemes import commutes_elementwise, decode_blocks, source_of_piece
 from ..kernels.mds_encode import skinny_gemm_pallas
-from ..kernels.ops import mds_encode, shard_map_compat
+from ..kernels.ops import interpret_default, mds_encode
 from ..launch.mesh import MODEL_AXIS, PiecePlacementError, make_local_mesh, \
     validate_pieces
 from ..launch.sharding import decode_block_spec, piece_spec
@@ -126,7 +126,8 @@ class MeshExecutor:
         ``decodable_prefix`` rule the threaded master applies at the k-th
         arrival.
     interpret:
-        Forwarded to the Pallas kernels (None = auto: interpret off-TPU).
+        Forwarded to the Pallas kernels (None = interpret on the CPU
+        backend only, compiled everywhere else).
 
     A program is built and jitted once per (kind, scheme, shapes, dtypes,
     stride, subset) — ``compile_count`` exposes cache fills so callers can
@@ -148,7 +149,7 @@ class MeshExecutor:
         self.order = None if order is None else tuple(int(p) for p in order)
         self.dead = tuple(int(p) for p in dead)
         self.stragglers = tuple(int(p) for p in stragglers)
-        self.interpret = interpret
+        self.interpret = interpret_default(interpret)
         self.pool = _MeshFleet(self.mesh, axis)
         self.elastic = False
         self.run_count = 0
@@ -161,7 +162,6 @@ class MeshExecutor:
         self.compile_count = 0
         self._programs: dict = {}
         self._chain_t = 0.0
-        self._sm = shard_map_compat()
 
     # -- executor contract (dist/backend.py) --------------------------------
     def close(self) -> None:
@@ -227,7 +227,7 @@ class MeshExecutor:
     def _build(self, op, subset: tuple[int, ...]):
         scheme, ndev = op.scheme, int(self.mesh.shape[self.axis])
         n, k = scheme.n, scheme.k
-        axis, mesh, sm = self.axis, self.mesh, self._sm
+        axis, mesh = self.axis, self.mesh
         interpret = self.interpret
         # masked/zeroed contributions: slices whose piece is not consumed
         # (beyond-n padding, dead-before-redispatch, stragglers past the
@@ -278,10 +278,10 @@ class MeshExecutor:
         # (k, t_p, d_in) -> (ndev, t_p, d_out); (k,N,C,H,Wp) -> (ndev,N,O,H',Wp')
         enc_arg = src if selection else Gp
         nd_out = op.x.ndim
-        fan_out = sm(
+        fan_out = jax.shard_map(
             worker, mesh=mesh,
             in_specs=(P(axis), P(axis), P(), P()),
-            out_specs=piece_spec(nd_out, axis), check_rep=False)
+            out_specs=piece_spec(nd_out, axis), check_vma=False)
         sub_idx = jnp.asarray(list(subset), jnp.int32)
         subset_l = list(subset)
 
@@ -289,9 +289,10 @@ class MeshExecutor:
             """Column-parallel decode: every slice recovers its own block
             of all k sources (the sharded skinny GEMM of eq. 4)."""
             spec = decode_block_spec(stacked.ndim, axis)
-            return sm(lambda blk: decode_blocks(scheme, subset_l, blk),
-                      mesh=mesh, in_specs=(spec,), out_specs=spec,
-                      check_rep=False)(stacked)
+            return jax.shard_map(
+                lambda blk: decode_blocks(scheme, subset_l, blk),
+                mesh=mesh, in_specs=(spec,), out_specs=spec,
+                check_vma=False)(stacked)
 
         def program(x, w):
             pieces = fan_out(enc_arg, mask, x, w)

@@ -50,8 +50,13 @@ def _conv_kernel(x_ref, w_ref, o_ref, *, kernel: int, stride: int,
 @functools.partial(jax.jit,
                    static_argnames=("stride", "block_co", "interpret"))
 def conv2d_pallas(x: jax.Array, w: jax.Array, stride: int = 1, *,
-                  block_co: int = BLOCK_CO, interpret: bool = True) -> jax.Array:
-    """x: (C_I, H_I, W_I), w: (C_O, C_I, K, K) -> (C_O, H_O, W_O)."""
+                  block_co: int = BLOCK_CO, interpret: bool) -> jax.Array:
+    """x: (C_I, H_I, W_I), w: (C_O, C_I, K, K) -> (C_O, H_O, W_O).
+
+    The caller chooses ``interpret``; the TPU compiler does not yet accept
+    this kernel (the patch reshape is an unsupported shape cast), so only
+    interpret mode runs it today.
+    """
     c_in, h_in, w_in = x.shape
     c_out, c_in2, K, K2 = w.shape
     assert c_in == c_in2 and K == K2

@@ -9,8 +9,8 @@ the encode, so it delegates to ``skinny_gemm_pallas``
 
 ``m`` is the number of received coded rows (m == k for MDS fastest-k; the
 LT scheme may decode from m > k rows via its host-side least-squares,
-which does not use this kernel).  ``interpret=None`` auto-detects the
-backend the same way as the encode.
+which does not use this kernel).  ``interpret=None`` means interpret mode
+on the CPU backend only, as for the encode.
 """
 from __future__ import annotations
 

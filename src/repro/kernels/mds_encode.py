@@ -3,20 +3,29 @@
 The paper's encode (eq. 3) is a skinny GEMM over the flattened input
 partitions: k is tiny (<= 16), F is huge (B*C_I*H_I*W_I^p).  On the Pi
 this runs on the master CPU; on TPU it is purely memory-bound, so the
-kernel streams F through VMEM in MXU-aligned tiles while the whole
-generator G stays resident:
-
-  grid  = (F // BLOCK_F,)
-  G     : (n, k)          VMEM-resident, same block every step
-  X     : (k, BLOCK_F)    streamed
-  out   : (n, BLOCK_F)    streamed
+kernel streams F through VMEM in lane-aligned tiles.
 
 The decode GEMM (kernels/mds_decode.py) has the identical structure with
-D = G_S^{-1} resident, so both delegate to one shared
-``skinny_gemm_pallas``.  BLOCK_F is a multiple of 128 (lane width);
-``interpret=None`` auto-detects the backend (interpret mode everywhere
-except a real TPU, so CPU CI and TPU serving both work with no caller
-flag).
+D = G_S^{-1}, and both backends' per-piece GEMM (a coded token slice times
+an FFN weight, dist/executor.py and dist/mesh_exec.py) is the same kernel
+at transformer widths, so all three delegate to one tiled
+``skinny_gemm_pallas``:
+
+  grid  = (M / bm, N / bn, K / bk)        K innermost, f32 accumulator
+  A     : (bm, bk)   x : (bk, bn)   out : (bm, bn)
+
+Rows and the contraction each form one full-extent block when they fit
+their cap (the encode's (n, k) generator stays resident); a larger one is
+cut into the largest aligned block that divides it, or padded.  Output
+columns always stream in ``block_f``-wide blocks (padded up), so every
+grid step computes the same (bm, bk) @ (bk, block_f) product whether a
+decode runs whole or split column-wise across a mesh — which keeps the two
+backends byte-identical.  The caps keep the double-buffered blocks near
+8 MiB of f32, inside the v5e's default scoped VMEM at every gemma-2b /
+minicpm-2b FFN shape.
+
+``interpret=None`` picks interpret mode on the CPU backend only; on any
+other backend the kernel is compiled.
 """
 from __future__ import annotations
 
@@ -25,47 +34,94 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["skinny_gemm_pallas", "mds_encode_pallas", "BLOCK_F"]
+__all__ = ["skinny_gemm_pallas", "mds_encode_pallas", "interpret_default",
+           "BLOCK_F"]
 
-BLOCK_F = 512
+BLOCK_F = 512      # streamed output-column block (lanes)
+BLOCK_M = 256      # cap of the row block (sublanes)
+BLOCK_K = 1024     # cap of the contraction block
 
 
-def _gemm_kernel(a_ref, x_ref, o_ref):
-    a = a_ref[...]          # (m, b) — resident
-    x = x_ref[...]          # (b, BLOCK_F) — streamed
-    o_ref[...] = jnp.dot(a, x, preferred_element_type=jnp.float32).astype(
-        o_ref.dtype)
+def interpret_default(interpret: bool | None = None) -> bool:
+    """Resolve an ``interpret=None`` request: interpret on the CPU only, so
+    a run on an accelerator never silently falls back to the interpreter."""
+    if interpret is not None:
+        return bool(interpret)
+    return jax.default_backend() == "cpu"
+
+
+def _block(dim: int, cap: int, align: int) -> tuple[int, int]:
+    """(block, padded extent) for one dimension: the whole extent when it
+    fits ``cap``, else the largest ``align``-multiple <= cap that divides
+    it (no copy), else ``cap`` with the extent padded up to a multiple."""
+    if dim <= cap:
+        return dim, dim
+    for b in range(cap - cap % align, cap // 4 - 1, -align):
+        if dim % b == 0:
+            return b, dim
+    return cap, dim + (-dim % cap)
+
+
+def _gemm_blocks(m: int, b: int, f: int, *, block_f: int = BLOCK_F
+                ) -> tuple[tuple[int, int], tuple[int, int], tuple[int, int]]:
+    """Block plan of an (m, b) @ (b, f) product: ((bm, Mp), (bk, Kp),
+    (bn, Fp)) — block size and padded extent of each dimension."""
+    return (_block(m, BLOCK_M, 16), _block(b, BLOCK_K, 128),
+            (block_f, f + (-f % block_f)))
+
+
+def _gemm_kernel(a_ref, x_ref, o_ref, acc_ref):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # HIGHEST: f32 operands multiply in full f32 on the MXU — a coded
+    # piece is a linear mix, and the decode amplifies any rounding in it
+    acc_ref[...] += jnp.dot(a_ref[...], x_ref[...],
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
+
+    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+    def _():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_f", "interpret"))
 def skinny_gemm_pallas(A: jax.Array, x: jax.Array, *, block_f: int = BLOCK_F,
                        interpret: bool | None = None) -> jax.Array:
-    """A: (m, b), x: (b, F) -> (m, F) with A resident and F streamed.
+    """A: (m, b), x: (b, F) -> (m, F) in x's dtype, accumulated in f32.
 
-    F is padded to a block_f multiple internally and sliced back.
+    Padding (only where no aligned block divides a dimension) is added
+    internally and sliced off.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_default(interpret)
     m, b = A.shape
     bx, F = x.shape
     assert bx == b, (A.shape, x.shape)
-    pad = -F % block_f
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad)))
-    Fp = F + pad
+    (bm, Mp), (bk, Kp), (bn, Fp) = _gemm_blocks(m, b, F, block_f=block_f)
+    A = A.astype(x.dtype)
+    if (Mp, Kp) != (m, b):
+        A = jnp.pad(A, ((0, Mp - m), (0, Kp - b)))
+    if (Kp, Fp) != (b, F):
+        x = jnp.pad(x, ((0, Kp - b), (0, Fp - F)))
     out = pl.pallas_call(
         _gemm_kernel,
-        out_shape=jax.ShapeDtypeStruct((m, Fp), x.dtype),
-        grid=(Fp // block_f,),
+        out_shape=jax.ShapeDtypeStruct((Mp, Fp), x.dtype),
+        grid=(Mp // bm, Fp // bn, Kp // bk),
         in_specs=[
-            pl.BlockSpec((m, b), lambda i: (0, 0)),          # A resident
-            pl.BlockSpec((b, block_f), lambda i: (0, i)),    # stream x
+            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
         ],
-        out_specs=pl.BlockSpec((m, block_f), lambda i: (0, i)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(A.astype(x.dtype), x)
-    return out[:, :F]
+        name="skinny_gemm",
+    )(A, x)
+    return out[:m, :F]
 
 
 def mds_encode_pallas(G: jax.Array, x: jax.Array, *, block_f: int = BLOCK_F,

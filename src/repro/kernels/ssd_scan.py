@@ -55,7 +55,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, h0_ref, y_ref, h1_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_chunk_pallas(x, dt, A, Bm, Cm, h0, *, interpret: bool = True):
+def ssd_chunk_pallas(x, dt, A, Bm, Cm, h0, *, interpret: bool):
     """Batched one-chunk SSD.
 
     x: (B, L, H, P), dt: (B, L, H), A: (H,), Bm/Cm: (B, L, N),

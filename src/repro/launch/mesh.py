@@ -24,12 +24,20 @@ class PiecePlacementError(ValueError):
     axis name, or an invalid requested axis split)."""
 
 
+def _auto_mesh(shape: tuple, axes: tuple) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with ``Auto`` axes: the coded programs leave
+    sharding propagation to the compiler (an ``Explicit`` axis would make
+    every gather name its output sharding)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """Single pod: (data=16, model=16) = 256 chips.
     Multi-pod: (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(*, model: int | None = None) -> jax.sharding.Mesh:
@@ -51,7 +59,7 @@ def make_local_mesh(*, model: int | None = None) -> jax.sharding.Mesh:
         raise PiecePlacementError(
             f"make_local_mesh: model={model} does not divide the "
             f"{ndev} available devices (the rest form the data axis)")
-    return jax.make_mesh((ndev // model, model), ("data", "model"))
+    return _auto_mesh((ndev // model, model), ("data", "model"))
 
 
 def validate_pieces(mesh: jax.sharding.Mesh, n: int,

@@ -85,7 +85,8 @@ def test_sharded_matches_local():
     from repro.core.coded_conv import coded_conv2d_sharded
 
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((1, n_dev), ("data", "model"))
+    mesh = jax.make_mesh((1, n_dev), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     spec = ConvSpec(c_in=4, c_out=6, h_in=8, w_in=12, kernel=3, stride=1)
     code = MDSCode(n_dev, max(n_dev - 1, 1))
     x, w = _rand_conv(jax.random.PRNGKey(0), spec)

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.core.coding import vandermonde_generator
+from repro.kernels.mds_encode import skinny_gemm_pallas
 from repro.kernels.ops import conv2d_subtask, mds_decode, mds_encode, ssd_chunk
 from repro.kernels.ref import (
     conv2d_ref,
@@ -61,6 +62,30 @@ class TestMDSDecodeKernel:
         back = mds_decode(D, coded[jnp.asarray(subset)], interpret=True)
         np.testing.assert_allclose(np.asarray(back), np.asarray(x),
                                    rtol=2e-3, atol=2e-3)
+
+
+class TestPieceGemmKernel:
+    """The tiled GEMM at shapes that take each tiling path: contraction
+    cut into aligned blocks or padded, rows cut or padded, columns padded."""
+
+    @pytest.mark.parametrize("m,b,f", [
+        (3, 2048, 700),     # two 1024-deep contraction blocks, F padded
+        (300, 256, 512),    # no aligned row block divides 300: rows padded
+        (320, 1100, 600),   # 160-row blocks; contraction padded to 2048
+        (171, 2048, 512),   # a prefill piece: one full-extent row block
+    ])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_matches_float64_product(self, m, b, f, dtype):
+        rng = np.random.default_rng(m + b + f)
+        a = jnp.asarray(rng.standard_normal((m, b)), jnp.float32)
+        x = jnp.asarray(rng.standard_normal((b, f)), dtype)
+        got = skinny_gemm_pallas(a, x, interpret=True)
+        want = (np.asarray(a.astype(dtype), np.float64)
+                @ np.asarray(x, np.float64))
+        assert got.shape == (m, f) and got.dtype == dtype
+        # f32: accumulation error only; bf16: the output's own rounding
+        bound = (2e-6 if dtype == jnp.float32 else 2 ** -8) * np.abs(want).max()
+        assert np.abs(np.asarray(got, np.float64) - want).max() <= bound
 
 
 class TestConv2dKernel:

@@ -11,26 +11,15 @@ from repro.configs import ARCHS, INPUT_SHAPES, get_config, for_shape
 from repro.models.model import init_cache, init_params
 
 
-def _mesh_stub(shape, axes):
-    """AbstractMesh: lets us build NamedShardings without 256 devices.
-
-    jax < 0.5 takes ``(name, size)`` pairs; jax >= 0.5 takes
-    ``(shape, axis_names)`` — support both.
-    """
-    try:
-        return jax.sharding.AbstractMesh(shape, axes)
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
-
-
 @pytest.fixture(scope="module")
 def mesh():
-    return _mesh_stub((16, 16), ("data", "model"))
+    # AbstractMesh: NamedShardings without 256 devices
+    return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
 
 
 @pytest.fixture(scope="module")
 def pod_mesh():
-    return _mesh_stub((2, 16, 16), ("pod", "data", "model"))
+    return jax.sharding.AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _check_divisible(tree, specs, mesh):
