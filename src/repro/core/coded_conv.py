@@ -31,13 +31,14 @@ Three execution modes:
 from __future__ import annotations
 
 import contextlib
-import threading
+import math
 from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..telemetry.trace import count, counting, span
 from .schemes import (CodingScheme, chunk_bounds, commutes_elementwise,
                       decode_blocks, resolve_subset, source_of_piece)
 from .splitting import (ChainPlan, ConvSpec, SegmentSplitPlan, SplitPlan,
@@ -62,10 +63,8 @@ __all__ = [
 # tests counting the operations the execution layer ACTUALLY performs, not
 # what the plan promises.  Selection schemes' encode/decode are flop-free
 # gathers but are still boundary operations (a master round-trip each), so
-# they count too.
-
-_OPS_TLS = threading.local()
-
+# they count too.  Every coded pipeline counts them as the telemetry
+# counters ``encodes`` / ``decodes`` (telemetry/trace.py).
 
 @contextlib.contextmanager
 def boundary_op_counter():
@@ -73,21 +72,21 @@ def boundary_op_counter():
 
     Yields a dict ``{"encode": int, "decode": int}`` updated in place by
     every coded pipeline run (per-layer or segment) entered under the
-    context.
+    context: a view of the ``encodes`` / ``decodes`` counters on this
+    thread.  It works outside any request too (the serving FFN, a
+    directly driven ``run_segment``), so it is a :func:`counting` view,
+    not a read of one request's record.
     """
-    counts = {"encode": 0, "decode": 0}
-    prev = getattr(_OPS_TLS, "counts", None)
-    _OPS_TLS.counts = counts
-    try:
-        yield counts
-    finally:
-        _OPS_TLS.counts = prev
+    with counting({"encodes": "encode", "decodes": "decode"}) as ops:
+        yield ops
 
 
-def _count_op(kind: str) -> None:
-    counts = getattr(_OPS_TLS, "counts", None)
-    if counts is not None:
-        counts[kind] += 1
+def _encode_span(scheme: CodingScheme, nbytes: int) -> span:
+    """Count one encode boundary (``encodes``) and return its
+    ``model.encode`` span; its ``segment`` arg is the request's encode
+    ordinal, its ``bytes`` those handed to the n pieces."""
+    return span("model.encode", segment=count("encodes"), n=scheme.n,
+                k=scheme.k, bytes=nbytes)
 
 
 ACTIVATIONS: dict[str, Callable[[jax.Array], jax.Array]] = {
@@ -163,28 +162,23 @@ def coded_conv2d(
     """
     if plan is None:
         plan = plan_width_split(spec, code.k)
-    parts = split_input(x, plan)  # (k, B, C, H, W_I^p)
-    if executor is not None and hasattr(executor, "run_op"):
-        # backend seam (dist/backend.py): the backend owns encode ->
-        # per-piece conv -> decode (the mesh backend fuses them into one
-        # shard_map program; the thread pool encodes eagerly and thunks)
+    # backend seam (dist/backend.py): the backend owns encode -> per-piece
+    # conv -> decode (the mesh backend fuses them into one shard_map
+    # program; the thread pool encodes eagerly and thunks)
+    seam = executor is not None and hasattr(executor, "run_op")
+    p0 = plan.parts[0]
+    with _encode_span(code, code.n * math.prod(x.shape[:-1])
+                      * (p0.b_i - p0.a_i) * x.dtype.itemsize):
+        parts = split_input(x, plan)  # (k, B, C, H, W_I^p)
+        if not seam:
+            coded_in = _encode_partitions(code, parts)  # (n, ...)
+    if seam:
         from ..dist.backend import CodedOp
 
-        _count_op("encode")
         y_parts = executor.run_op(
             CodedOp("conv2d", code, parts, w, spec=spec,
                     assignment=assignment))
-        _count_op("decode")
-        y = jnp.concatenate(list(y_parts), axis=-1)
-        if plan.remainder is not None:
-            pr = plan.remainder
-            y_rem = conv2d(x[..., pr.a_i : pr.b_i], w, spec.stride)
-            y = jnp.concatenate([y, y_rem], axis=-1)
-        return y
-    coded_in = _encode_partitions(code, parts)  # (n, ...)
-    _count_op("encode")
-
-    if executor is not None:
+    elif executor is not None:
         # legacy thunk surface: pre-seam executors and test doubles
         y_parts = executor.run(
             code,
@@ -195,21 +189,25 @@ def coded_conv2d(
     else:
         subset = resolve_subset(code, subset)
         # Execution phase: each worker i computes f(X~_i), same weights.
-        coded_out = jax.vmap(lambda xi: conv2d(xi, w, spec.stride))(coded_in)
-
+        with span("model.local"):
+            coded_out = jax.vmap(lambda xi: conv2d(xi, w, spec.stride))(
+                coded_in)
         # Decoding phase: any sufficient subset of outputs decodes (eq. 4).
-        sel = coded_out[jnp.asarray(subset)]
-        flat = sel.reshape(len(subset), -1)
-        decoded = code.decode_from(subset, flat)
-        y_parts = decoded.reshape((code.k,) + coded_out.shape[1:])
-    _count_op("decode")
+        with span("model.decode", n=code.n, k=code.k, pieces=len(subset)):
+            sel = coded_out[jnp.asarray(subset)]
+            flat = sel.reshape(len(subset), -1)
+            decoded = code.decode_from(subset, flat)
+            y_parts = decoded.reshape((code.k,) + coded_out.shape[1:])
+    count("decodes")
 
     # Reassemble on the width dim; master-kept remainder (footnote 2).
-    y = jnp.concatenate(list(y_parts), axis=-1)
+    with span("model.decode", n=code.n, k=code.k):
+        y = jnp.concatenate(list(y_parts), axis=-1)
     if plan.remainder is not None:
-        r = plan.remainder
-        y_rem = conv2d(x[..., r.a_i : r.b_i], w, spec.stride)
-        y = jnp.concatenate([y, y_rem], axis=-1)
+        with span("model.remainder"):
+            r = plan.remainder
+            y_rem = conv2d(x[..., r.a_i : r.b_i], w, spec.stride)
+            y = jnp.concatenate([y, y_rem], axis=-1)
     return y
 
 
@@ -304,16 +302,21 @@ def run_segment(
     if commuting:
         # selection dispatch: piece i carries its source partition's slice
         # verbatim (edge chains are narrower — no row-stacking involved)
-        srcs = [source_of_piece(scheme, i) for i in range(scheme.n)]
-        piece_part = [split.parts[s] for s in srcs]
-        piece_in = [x[..., cp.entry.a_i:cp.entry.b_i] for cp in piece_part]
+        piece_part = [split.parts[source_of_piece(scheme, i)]
+                      for i in range(scheme.n)]
     else:
-        parts = jnp.stack(
-            [x[..., cp.entry.a_i:cp.entry.b_i] for cp in split.parts])
-        coded_in = _encode_partitions(scheme, parts)
         piece_part = [split.parts[0]] * scheme.n
-        piece_in = [coded_in[i] for i in range(scheme.n)]
-    _count_op("encode")
+    width = sum(cp.entry.b_i - cp.entry.a_i for cp in piece_part)
+    with _encode_span(scheme, width * math.prod(x.shape[:-1])
+                      * x.dtype.itemsize):
+        if commuting:
+            piece_in = [x[..., cp.entry.a_i:cp.entry.b_i]
+                        for cp in piece_part]
+        else:
+            parts = jnp.stack(
+                [x[..., cp.entry.a_i:cp.entry.b_i] for cp in split.parts])
+            coded_in = _encode_partitions(scheme, parts)
+            piece_in = [coded_in[i] for i in range(scheme.n)]
     chunks = max(1, int(stream_chunks)) if stream_chunks else 1
 
     def _piece(i: int) -> jax.Array:
@@ -335,18 +338,24 @@ def run_segment(
         )  # (k, B, C_O, H_O, W_O^p)
     else:
         subset = resolve_subset(scheme, subset)
-        outs = jnp.stack([_piece(i) for i in subset])
-        y_parts = decode_blocks(scheme, subset, outs, chunks=chunks)
-    _count_op("decode")
+        with span("model.local"):
+            outs = [_piece(i) for i in subset]
+        with span("model.decode", n=scheme.n, k=scheme.k,
+                  pieces=len(subset)):
+            y_parts = decode_blocks(scheme, subset, jnp.stack(outs),
+                                    chunks=chunks)
+    count("decodes")
 
-    y = jnp.concatenate(list(y_parts), axis=-1)
+    with span("model.decode", n=scheme.n, k=scheme.k):
+        y = jnp.concatenate(list(y_parts), axis=-1)
     if split.remainder is not None:
         # footnote 2 at segment granularity: the master runs the remainder
         # columns' whole chain locally, on true values (acts always apply)
-        y_rem = _chain(
-            x[..., split.remainder.entry.a_i:split.remainder.entry.b_i],
-            split.remainder, weights, specs, pads, acts, apply_acts=True)
-        y = jnp.concatenate([y, y_rem], axis=-1)
+        with span("model.remainder"):
+            y_rem = _chain(
+                x[..., split.remainder.entry.a_i:split.remainder.entry.b_i],
+                split.remainder, weights, specs, pads, acts, apply_acts=True)
+            y = jnp.concatenate([y, y_rem], axis=-1)
     return y
 
 

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .coded_conv import _count_op
+from ..telemetry.trace import count
 from .schemes import (CodingScheme, commutes_elementwise, resolve_subset,
                       source_of_piece)
 from .splitting import SplitPlan, plan_token_split
@@ -66,16 +66,16 @@ def coded_matmul(
         from ..dist.backend import CodedOp
 
         parts = x[: code.k * plan.w_out_p].reshape(code.k, plan.w_out_p, -1)
-        _count_op("encode")
+        count("encodes")
         decoded = executor.run_op(
             CodedOp("matmul", code, parts, w, assignment=assignment))
         y = decoded.reshape(code.k * plan.w_out_p, w.shape[-1])
-        _count_op("decode")
+        count("decodes")
         if plan.remainder is not None:
             y = jnp.concatenate([y, x[plan.remainder.a_i :] @ w], axis=0)
         return y
     coded_in = _encode_tokens(code, x, plan)  # (n, T_p, d_in)
-    _count_op("encode")
+    count("encodes")
     if executor is not None:
         # legacy thunk surface: pre-seam executors and test doubles
         decoded = executor.run(
@@ -90,7 +90,7 @@ def coded_matmul(
         sel = coded_out[jnp.asarray(subset)]
         decoded = code.decode_from(subset, sel.reshape(len(subset), -1))
         y = decoded.reshape(code.k * plan.w_out_p, w.shape[-1])
-    _count_op("decode")
+    count("decodes")
     if plan.remainder is not None:
         y = jnp.concatenate([y, x[plan.remainder.a_i :] @ w], axis=0)
     return y
@@ -138,7 +138,7 @@ def coded_ffn_segment(
     t_p = plan.w_out_p
     srcs = [source_of_piece(code, i) for i in range(code.n)]
     piece_in = [x[s * t_p:(s + 1) * t_p] for s in srcs]
-    _count_op("encode")  # the selection dispatch is the boundary op
+    count("encodes")  # the selection dispatch is the boundary op
     if executor is not None:
         decoded = executor.run(
             code, [lambda i=i: chain(piece_in[i]) for i in range(code.n)],
@@ -149,7 +149,7 @@ def coded_ffn_segment(
         outs = jnp.stack([chain(piece_in[i]) for i in subset])
         decoded = code.decode_from(subset, outs.reshape(len(subset), -1))
         y = decoded.reshape(code.k * t_p, w_out.shape[-1])
-    _count_op("decode")
+    count("decodes")
     if plan.remainder is not None:
         y = jnp.concatenate([y, chain(x[plan.remainder.a_i:])], axis=0)
     return y

@@ -28,6 +28,7 @@ from typing import Any, Callable, Sequence
 import jax.numpy as jnp
 
 from ..core.schemes import CodingScheme, decode_blocks
+from ..telemetry.trace import span
 from .clock import Clock
 from .faults import ChurnSchedule, DelayModel, FaultPlan
 from .pool import RunHandle, RunReport, WorkerPool
@@ -53,10 +54,11 @@ def decodable_prefix(scheme: CodingScheme, order: Sequence[int]) -> list[int] | 
 
 
 class ExecHandle:
-    """One in-flight coded run; ``result()`` collects, decodes, and books
-    the run into the executor's telemetry (last_report / run_count /
-    on_report / chain gate) — in *resolution* order, which for overlapped
-    runs is the caller's join order."""
+    """One in-flight coded run; ``wait()`` collects it to its accepting
+    arrival and books the run into the executor's telemetry (last_report /
+    run_count / on_report / chain gate) — in *resolution* order, which for
+    overlapped runs is the caller's join order; ``result()`` also decodes
+    (a ``model.decode`` span)."""
 
     def __init__(self, ex: "CodedExecutor", scheme: CodingScheme,
                  handle: RunHandle, decode_chunks: int):
@@ -64,6 +66,7 @@ class ExecHandle:
         self._scheme = scheme
         self._handle = handle
         self._decode_chunks = decode_chunks
+        self._results: dict | None = None
         self._out: jnp.ndarray | None = None
 
     @property
@@ -73,10 +76,13 @@ class ExecHandle:
     def cancel(self) -> None:
         self._handle.cancel()
 
-    def result(self) -> jnp.ndarray:
-        if self._out is not None:
-            return self._out
+    def wait(self) -> RunReport:
+        """Collect the run up to its accepting arrival and book it; no
+        decode.  Repeat calls return the booked report."""
+        if self._results is not None:
+            return self._handle.report
         results, report = self._handle.result()
+        self._results = results
         ex, scheme = self._ex, self._scheme
         ex.last_report = report
         ex.run_count += 1
@@ -84,20 +90,39 @@ class ExecHandle:
             ex._chain_t = max(ex._chain_t, report.t_complete)
         if ex.trace_sink is not None:
             from ..telemetry.trace import Span
-            origin = float(getattr(ex.trace_sink, "origin", 0.0))
-            ex.trace_sink.span(Span(
-                "run", "exec", origin + report.t_submit,
-                max(report.t_complete - report.t_submit, 0.0), "pool",
-                {"n": scheme.n, "k": scheme.k,
-                 "pieces": len(report.assignment),
-                 "redispatches": len(report.redispatched),
-                 "decoded": len(report.subset)}))
+            args = {"n": scheme.n, "k": scheme.k,
+                    "pieces": len(report.assignment),
+                    "redispatches": len(report.redispatched),
+                    "decoded": len(report.subset)}
+            if ex.pool.clock.virtual:
+                origin = float(getattr(ex.trace_sink, "origin", 0.0))
+                ex.trace_sink.span(Span(
+                    "run", "exec", origin + report.t_submit,
+                    max(report.t_complete - report.t_submit, 0.0), "pool",
+                    args))
+            else:  # measured: submit to accepting arrival
+                link = self._handle.link
+                ex.trace_sink.span(Span(
+                    "run", "exec", self._handle.wall0, report.wall_s,
+                    "pool", args,
+                    req=None if link is None or link.rec is None
+                    else link.rec.id,
+                    parent=None if link is None else link.sid))
         if ex.on_report is not None:
             ex.on_report(report)
-        subset = report.subset
-        stacked = jnp.stack([jnp.asarray(results[i]) for i in subset])
-        self._out = decode_blocks(scheme, subset, stacked,
-                                  chunks=self._decode_chunks)
+        return report
+
+    def result(self) -> jnp.ndarray:
+        if self._out is not None:
+            return self._out
+        subset = self.wait().subset
+        scheme = self._scheme
+        with span("model.decode", n=scheme.n, k=scheme.k,
+                  pieces=len(subset)):
+            stacked = jnp.stack([jnp.asarray(self._results[i])
+                                 for i in subset])
+            self._out = decode_blocks(scheme, subset, stacked,
+                                      chunks=self._decode_chunks)
         return self._out
 
 
@@ -193,16 +218,21 @@ class CodedExecutor:
         from ..kernels.mds_encode import skinny_gemm_pallas
 
         scheme = op.scheme
+        with span("model.encode", n=scheme.n, k=scheme.k,
+                  bytes=scheme.n * op.x.nbytes // op.x.shape[0]):
+            if op.kind == "matmul":
+                k, t_p, d = op.x.shape
+                coded_in = scheme.encode(op.x.reshape(k, -1)).reshape(
+                    scheme.n, t_p, d)
+            else:
+                coded_in = _encode_partitions(scheme, op.x)
         if op.kind == "matmul":
-            k, t_p, d = op.x.shape
-            coded_in = scheme.encode(op.x.reshape(k, -1)).reshape(scheme.n, t_p, d)
             # the SAME worker kernel the mesh backend shards — a plain `@`
             # lets XLA pick a shape-dependent GEMM algorithm, which breaks
             # byte-for-byte equality across backends at some piece shapes
             fns = [lambda i=i: skinny_gemm_pallas(coded_in[i], op.w)
                    for i in range(scheme.n)]
         else:
-            coded_in = _encode_partitions(scheme, op.x)
             fns = [
                 lambda i=i: conv2d(coded_in[i], op.w, op.spec.stride)
                 for i in range(scheme.n)
@@ -385,7 +415,10 @@ class CodedExecutor:
         otherwise keep believing whatever it last saw (survivorship bias;
         see dist/adaptive.py).
         """
-        return self.run_async(
-            scheme, piece_fns, assignment=assignment, speeds=speeds,
-            fault_plan=fault_plan, delay_model=delay_model,
-            gather_all=gather_all, decode_chunks=decode_chunks).result()
+        with span("backend.run", n=scheme.n, k=scheme.k):
+            h = self.run_async(
+                scheme, piece_fns, assignment=assignment, speeds=speeds,
+                fault_plan=fault_plan, delay_model=delay_model,
+                gather_all=gather_all, decode_chunks=decode_chunks)
+            h.wait()
+        return h.result()

@@ -67,6 +67,7 @@ import threading
 import time
 from typing import Any, Callable, Sequence
 
+from ..telemetry.trace import adopt, handoff, record, span
 from .clock import Clock, FakeClock, RealClock
 from .faults import DelayModel, FaultPlan
 
@@ -91,6 +92,7 @@ class Piece:
     idx: int
     fn: Callable[[], Any]
     not_before: float = 0.0  # virtual gate: re-dispatches start >= t_detect
+    queued_ns: int = 0       # perf_counter_ns of the inbox put
 
 
 @dataclasses.dataclass(frozen=True)
@@ -110,7 +112,11 @@ class PieceTiming:
     mode, the measured compute time in measured mode), and
     ``t_arrival = t_dispatch + t_compute`` its completion at the master.
     Queueing behind other runs in a group widens ``t_dispatch`` only —
-    ``t_compute`` is pure service time, never contention.
+    ``t_compute`` is pure service time, never contention.  ``compute_s``
+    is the measured wall time of the piece's thunk alone, any injected
+    straggler or delay-model time excluded; ``wall`` the piece's measured
+    (start, arrival) on the ``perf_counter`` clock on a real clock, empty
+    on a virtual one.
     """
 
     worker: int
@@ -124,6 +130,8 @@ class PieceTiming:
     # MORE than the pipelined t_compute; the gap is the overlapped
     # ship/compute time.  Empty for measured mode.
     stages: tuple = ()
+    compute_s: float = 0.0
+    wall: tuple = ()
 
 
 @dataclasses.dataclass
@@ -158,6 +166,7 @@ class _RunCtx:
     t0_wall: float   # wall origin of the run's GROUP (shared across a group)
     start_at: float  # virtual gate: no piece of this run starts earlier
     post: Callable[["_Event"], None]
+    link: Any = None  # the request and span that dispatched it (telemetry)
 
 
 @dataclasses.dataclass
@@ -170,6 +179,8 @@ class _Event:
     payload: Any = None
     t_start: float = 0.0  # virtual time the worker began serving the piece
     stages: tuple = ()    # per-layer durations (segment pieces)
+    compute_s: float = 0.0  # measured thunk time
+    wall: tuple = ()        # real clocks: measured (start, arrival) seconds
 
 
 @dataclasses.dataclass
@@ -235,6 +246,16 @@ class RunHandle:
     def report(self) -> RunReport:
         """The run's report (complete only after :meth:`result`)."""
         return self._report
+
+    @property
+    def wall0(self) -> float:
+        """``perf_counter`` seconds at the run's submission."""
+        return self._wall0
+
+    @property
+    def link(self):
+        """The request and span that dispatched the run (telemetry)."""
+        return self._ctx.link
 
     def cancel(self) -> None:
         """Abort the run's stragglers (real-clock early exit)."""
@@ -534,51 +555,70 @@ class WorkerPool:
                 # immediate removal: the master already posted this run's
                 # failure; serve nothing further
                 continue
-            leave = self._leave_at.get(w)
-            if leave is not None and ctx.group >= leave[0]:
-                # scripted departure (virtual clocks): pieces finishing by
-                # the departure instant still count; the first too-late
-                # piece posts the failure AT that instant — deterministic
-                # because this thread posts serially with monotone t — and
-                # the worker serves nothing further for the run (prog[1]).
-                t_rm = leave[1] if ctx.group == leave[0] else 0.0
-                dur = self._duration(ctx, w, piece)
-                if max(t_free, ctx.start_at, piece.not_before) + dur > t_rm:
-                    prog[1] = True
-                    ctx.post(_Event("failure", ctx.epoch, w, piece.idx, t_rm))
-                    continue
-            fail_at = ctx.faults.fails_at(w)
-            if fail_at is not None and prog[0] >= fail_at:
-                # die on this piece; detection at the would-be completion
-                # (core/runtime.py failure semantics)
-                dur = self._duration(ctx, w, piece)
-                t_detect = max(t_free, ctx.start_at, piece.not_before) + dur
+            with adopt(ctx.link):
+                t_free = self._serve(ctx, w, piece, prog, t_free)
+
+    def _serve(self, ctx: _RunCtx, w: int, piece: Piece, prog: list,
+               t_free: float) -> float:
+        """Serve one piece on worker ``w``; returns the worker's new
+        ``t_free``.  Runs inside the dispatching request's telemetry:
+        ``backend.queue`` (inbox put -> start), ``backend.compute`` (the
+        thunk, measured) and, on a real clock, ``backend.delay`` (the
+        injected straggler or delay-model sleep, cancelled or not)."""
+        leave = self._leave_at.get(w)
+        if leave is not None and ctx.group >= leave[0]:
+            # scripted departure (virtual clocks): pieces finishing by
+            # the departure instant still count; the first too-late
+            # piece posts the failure AT that instant — deterministic
+            # because this thread posts serially with monotone t — and
+            # the worker serves nothing further for the run (prog[1]).
+            t_rm = leave[1] if ctx.group == leave[0] else 0.0
+            dur = self._duration(ctx, w, piece)
+            if max(t_free, ctx.start_at, piece.not_before) + dur > t_rm:
                 prog[1] = True
-                if not ctx.clock.virtual:
+                ctx.post(_Event("failure", ctx.epoch, w, piece.idx, t_rm))
+                return t_free
+        fail_at = ctx.faults.fails_at(w)
+        if fail_at is not None and prog[0] >= fail_at:
+            # die on this piece; detection at the would-be completion
+            # (core/runtime.py failure semantics)
+            dur = self._duration(ctx, w, piece)
+            t_detect = max(t_free, ctx.start_at, piece.not_before) + dur
+            prog[1] = True
+            if not ctx.clock.virtual:
+                with span("backend.delay", piece=piece.idx, worker=w):
                     self._sleep_until(ctx, t_detect)
-                ctx.post(_Event("failure", ctx.epoch, w, piece.idx, t_detect))
-                continue
-            try:
-                t0 = time.perf_counter()
+            ctx.post(_Event("failure", ctx.epoch, w, piece.idx, t_detect))
+            return t_free
+        record("backend.queue", piece.queued_ns, time.perf_counter_ns(),
+               piece=piece.idx, worker=w)
+        try:
+            with span("backend.compute", piece=piece.idx, worker=w) as sc:
                 result = piece.fn()  # the real subtask compute
                 if hasattr(result, "block_until_ready"):
                     result.block_until_ready()
-                elapsed = time.perf_counter() - t0
-            except Exception as e:  # master re-raises
-                ctx.post(_Event("error", ctx.epoch, w, piece.idx, t_free,
-                                payload=e))
-                prog[1] = True
-                continue
-            dur = self._duration(ctx, w, piece, measured=elapsed)
-            stages = self._stage_durations(ctx, w, piece)
-            t_start = max(t_free, ctx.start_at, piece.not_before)
-            t_fin = t_start + dur
-            t_free, prog[0] = t_fin, prog[0] + 1
-            if not ctx.clock.virtual:
-                if not self._sleep_until(ctx, t_fin):
-                    continue  # cancelled mid-sleep: drop the late result
-            ctx.post(_Event("arrival", ctx.epoch, w, piece.idx, t_fin,
-                            payload=result, t_start=t_start, stages=stages))
+        except Exception as e:  # master re-raises
+            ctx.post(_Event("error", ctx.epoch, w, piece.idx, t_free,
+                            payload=e))
+            prog[1] = True
+            return t_free
+        elapsed = sc.dur_ns * 1e-9
+        dur = self._duration(ctx, w, piece, measured=elapsed)
+        stages = self._stage_durations(ctx, w, piece)
+        t_start = max(t_free, ctx.start_at, piece.not_before)
+        t_fin = t_start + dur
+        prog[0] += 1
+        wall = ()
+        if not ctx.clock.virtual:
+            with span("backend.delay", piece=piece.idx, worker=w):
+                arrived = self._sleep_until(ctx, t_fin)
+            if not arrived:
+                return t_fin  # cancelled mid-sleep: drop the late result
+            wall = (sc.t0_ns * 1e-9, time.perf_counter_ns() * 1e-9)
+        ctx.post(_Event("arrival", ctx.epoch, w, piece.idx, t_fin,
+                        payload=result, t_start=t_start, stages=stages,
+                        compute_s=elapsed, wall=wall))
+        return t_fin
 
     def _duration(self, ctx: _RunCtx, w: int, piece: Piece, *,
                   measured: float | None = None) -> float:
@@ -678,6 +718,16 @@ class WorkerPool:
         extras = list(extra_pieces or [])
         thunks: dict[int, Callable[[], Any]] = {
             i: fn for i, fn in enumerate(pieces)}
+        link = handoff()  # the request and span the pieces serve
+        with span("backend.dispatch", pieces=n + len(extras)):
+            return self._submit(n, thunks, extras, until, viable,
+                                assignment, faults, delay, start_at,
+                                workers, link)
+
+    def _submit(self, n: int, thunks: dict, extras: list, until, viable,
+                assignment, faults: FaultPlan, delay: DelayModel | None,
+                start_at: float, workers, link) -> RunHandle:
+        """Place one run's pieces in the workers' inboxes (``run_async``)."""
         wall0 = time.perf_counter()
         events: queue.Queue[_Event] = queue.Queue()
         with self._submit_lock:
@@ -714,7 +764,8 @@ class WorkerPool:
             self._active += 1
             ctx = _RunCtx(self._epoch, self._group, threading.Event(),
                           faults, delay, self.clock, self.time_scale,
-                          self._group_t0_wall, float(start_at), events.put)
+                          self._group_t0_wall, float(start_at),
+                          events.put, link)
             # master state.  Receipt-time state (pending / arrived / last_t)
             # is OS-scheduling dependent and is used ONLY for the safe-merge
             # bound and liveness; every decision that shapes the run (decode
@@ -730,7 +781,8 @@ class WorkerPool:
             for w in range(self.n_workers):
                 for i in sorted(st.pending[w]):
                     self._inbox[w].put((ctx, Piece(
-                        i, thunks[i], not_before=gates.get(i, 0.0))))
+                        i, thunks[i], not_before=gates.get(i, 0.0),
+                        queued_ns=time.perf_counter_ns())))
                     self.dispatch_count += 1
             self._live[ctx.epoch] = (ctx, st)
         report = RunReport(0.0, 0.0, [], [], [], [], [], dict(owner),
@@ -754,7 +806,7 @@ class WorkerPool:
                                                          FakeClock):
                         self.clock.advance(done)
                     if self.trace_sink is not None:
-                        self._emit_spans(report)
+                        self._emit_spans(report, ctx)
                     return ({i: st.results[i] for i in report.subset},
                             report)
                 if not any(st.pending) and not st.heap:
@@ -766,7 +818,8 @@ class WorkerPool:
                     raise RuntimeError(
                         "pool exhausted: every piece arrived but the "
                         f"completion rule never accepted (arrived={st.order})")
-                ev = self._next_event(h._events)
+                with span("backend.wait"):
+                    ev = self._next_event(h._events)
                 if ev.kind == "error":
                     raise RuntimeError(
                         f"worker {ev.worker} raised on piece {ev.piece}"
@@ -782,28 +835,39 @@ class WorkerPool:
                 self._active -= 1
                 self._live.pop(ctx.epoch, None)
 
-    def _emit_spans(self, report: "RunReport") -> None:
+    def _emit_spans(self, report: "RunReport", ctx: _RunCtx) -> None:
         """Feed one resolved run's piece timings to the trace sink.
 
         Times are group-relative; the sink's ``origin`` (0.0 when absent)
         places them on the caller's timeline.  Stage phases are laid out
         cumulatively from the dispatch instant, but only when the stage
         sum fits inside the round trip — pipelined chunked stages overlap
-        in time and cannot honestly be placed end-to-end.
+        in time and cannot honestly be placed end-to-end.  On a real clock
+        a piece spans its measured start to arrival (``PieceTiming.wall``,
+        ``perf_counter`` seconds) and carries the dispatching request's and
+        span's ids.
         """
         from ..telemetry.trace import Span
         sink = self.trace_sink
         origin = float(getattr(sink, "origin", 0.0))
+        link = ctx.link
+        measured = {} if link is None else {
+            "req": None if link.rec is None else link.rec.id,
+            "parent": link.sid}
         for tm in report.timings:
             tid = f"worker-{tm.worker}"
-            sink.span(Span("piece", "pool", origin + tm.t_dispatch,
-                           tm.t_compute, tid, {"piece": tm.piece}))
-            if tm.stages and sum(tm.stages) <= tm.t_compute * (1 + 1e-9) + 1e-12:
-                t = origin + tm.t_dispatch
-                for j, dur in enumerate(tm.stages):
-                    sink.span(Span("phase", "pool", t, dur, tid,
-                                   {"piece": tm.piece, "stage": j}))
-                    t += dur
+            if tm.wall:
+                t0, dur, ids = tm.wall[0], tm.wall[1] - tm.wall[0], measured
+            else:
+                t0, dur, ids = origin + tm.t_dispatch, tm.t_compute, {}
+            sink.span(Span("piece", "pool", t0, dur, tid,
+                           {"piece": tm.piece}, **ids))
+            if tm.stages and sum(tm.stages) <= dur * (1 + 1e-9) + 1e-12:
+                t = t0
+                for j, d in enumerate(tm.stages):
+                    sink.span(Span("phase", "pool", t, d, tid,
+                                   {"piece": tm.piece, "stage": j}, **ids))
+                    t += d
 
     def _initial_assignment(self, n: int, counts,
                             cand: Sequence[int]) -> dict[int, int]:
@@ -857,7 +921,7 @@ class WorkerPool:
                 report.arrivals.append(Arrival(ev.worker, ev.piece, ev.t))
                 report.timings.append(PieceTiming(
                     ev.worker, ev.piece, ev.t_start, ev.t - ev.t_start, ev.t,
-                    stages=ev.stages))
+                    stages=ev.stages, compute_s=ev.compute_s, wall=ev.wall))
                 subset = until(list(st.order))
                 if subset is not None:
                     report.subset = list(subset)
@@ -939,6 +1003,7 @@ class WorkerPool:
                 report.assignment[p] = tgt
                 report.redispatched.append((p, src, tgt))
                 self._inbox[tgt].put(
-                    (ctx, Piece(p, st.thunks[p], not_before=t_detect)))
+                    (ctx, Piece(p, st.thunks[p], not_before=t_detect,
+                                queued_ns=time.perf_counter_ns())))
                 self.dispatch_count += 1
         st.lost.clear()

@@ -34,6 +34,7 @@ from ..core.netplan import (LayerInfo, LocalStep, NetPlan, SegmentStep,
                             compile_plan)
 from ..core.schemes import CodingScheme, get_scheme
 from ..core.splitting import ConvSpec
+from ..telemetry.trace import span
 
 __all__ = ["LayerInfo", "vgg16_conv_specs", "resnet18_conv_specs",
            "is_type1", "type1_threshold", "maxpool2d", "forward_plan",
@@ -188,23 +189,40 @@ def forward_plan(plan: NetPlan, convs: Sequence[jax.Array], x: jax.Array,
     Segments execute ``run_segment`` (one encode, resident chains, one
     decode; interior activations inside the chains); the master applies
     each segment's final activation and pooling post-decode, and runs
-    LocalStep layers itself.  ``convs[i]`` is layer i's OIHW weight.
+    LocalStep layers itself (``model.local`` spans, as is the segment's
+    entry pad).  ``convs[i]`` is layer i's OIHW weight.
     """
     for step in plan.steps:
         sub = plan.layers[step.start:step.stop]
         ws = [convs[i] for i in range(step.start, step.stop)]
         if isinstance(step, SegmentStep):
+            with span("model.local"):
+                xp = _pad_hw(x, sub[0].pad)
             y = run_segment(
-                _pad_hw(x, sub[0].pad), ws, step.scheme,
+                xp, ws, step.scheme,
                 [li.spec for li in sub], [li.pad for li in sub],
                 [li.act for li in sub], split=step.split, subset=subset,
                 executor=executor, assignment=assignment)
-            x = _finish_layer(y, sub[-1])
+            with span("model.local"):
+                x = _finish_layer(y, sub[-1])
         else:
-            for li, w in zip(sub, ws):
-                x = _finish_layer(conv2d(_pad_hw(x, li.pad), w,
-                                         li.spec.stride), li)
+            with span("model.local"):
+                x = _local_layers(sub, ws, x)
     return x
+
+
+def _local_layers(layers: Sequence[LayerInfo], convs, x: jax.Array
+                  ) -> jax.Array:
+    """Run conv layers (pad, conv, activation, pool) on the master."""
+    for li, w in zip(layers, convs):
+        x = _finish_layer(conv2d(_pad_hw(x, li.pad), w, li.spec.stride), li)
+    return x
+
+
+def _head(h: jax.Array, head: jax.Array) -> jax.Array:
+    """Flatten and apply the linear head, on the master."""
+    with span("model.local"):
+        return h.reshape(h.shape[0], -1) @ head
 
 
 def cnn_head_features(layers: Sequence[LayerInfo]) -> int:
@@ -330,20 +348,18 @@ def small_cnn_forward(
     ``subset`` (default: each scheme's ``default_subset``) picks the
     worker outputs decode consumes, emulating stragglers.
     """
-    layers = small_cnn_layers(image=x.shape[-1],
-                              params=sys_params or SMALL_CNN_PARAMS)
-    plan = _resolve_plan(layers, plan, scheme, code, n,
-                         sys_params or SMALL_CNN_PARAMS)
-    if plan is None:
-        h = x
-        for li, w in zip(layers, params["convs"]):
-            h = _finish_layer(conv2d(_pad_hw(h, li.pad), w, li.spec.stride),
-                              li)
-    else:
-        h = forward_plan(plan, params["convs"], x, subset=subset,
-                         executor=executor)
-    h = h.reshape(h.shape[0], -1)
-    return h @ params["head"]
+    with span("model.forward", batch=x.shape[0]):
+        layers = small_cnn_layers(image=x.shape[-1],
+                                  params=sys_params or SMALL_CNN_PARAMS)
+        plan = _resolve_plan(layers, plan, scheme, code, n,
+                             sys_params or SMALL_CNN_PARAMS)
+        if plan is None:
+            with span("model.local"):
+                h = _local_layers(layers, params["convs"], x)
+        else:
+            h = forward_plan(plan, params["convs"], x, subset=subset,
+                             executor=executor)
+        return _head(h, params["head"])
 
 
 # ---------------------------------------------------------------------------
@@ -367,18 +383,16 @@ def vgg16_forward(
     executor=None,
 ) -> jax.Array:
     """Runnable VGG16: 13-conv stack through the compiled segment plan."""
-    layers = vgg16_conv_specs(image=x.shape[-1], params=sys_params)
-    plan = _resolve_plan(layers, plan, scheme, code, n, sys_params)
-    if plan is None:
-        h = x
-        for li, w in zip(layers, params["convs"]):
-            h = _finish_layer(conv2d(_pad_hw(h, li.pad), w, li.spec.stride),
-                              li)
-    else:
-        h = forward_plan(plan, params["convs"], x, subset=subset,
-                         executor=executor)
-    h = h.reshape(h.shape[0], -1)
-    return h @ params["head"]
+    with span("model.forward", batch=x.shape[0]):
+        layers = vgg16_conv_specs(image=x.shape[-1], params=sys_params)
+        plan = _resolve_plan(layers, plan, scheme, code, n, sys_params)
+        if plan is None:
+            with span("model.local"):
+                h = _local_layers(layers, params["convs"], x)
+        else:
+            h = forward_plan(plan, params["convs"], x, subset=subset,
+                             executor=executor)
+        return _head(h, params["head"])
 
 
 def init_resnet18(key: jax.Array, n_classes: int = 10, image: int = 64) -> dict:
@@ -416,25 +430,29 @@ def resnet18_forward(
     under selection schemes and stays per-layer under linear mixes; the
     skip add and the following relu are master-side joins (barriers).
     """
-    layers = resnet18_conv_specs(image=x.shape[-1], params=sys_params)
-    convs = params["convs"]
+    with span("model.forward", batch=x.shape[0]):
+        layers = resnet18_conv_specs(image=x.shape[-1], params=sys_params)
+        convs = params["convs"]
 
-    def branch(idxs: Sequence[int], h: jax.Array) -> jax.Array:
-        sub = [layers[i] for i in idxs]
-        pln = _resolve_plan(sub, None, scheme, code, n, sys_params)
-        if pln is None:
-            for li, w in zip(sub, (convs[i] for i in idxs)):
-                h = _finish_layer(conv2d(_pad_hw(h, li.pad), w,
-                                         li.spec.stride), li)
-            return h
-        return forward_plan(pln, {i: convs[j] for i, j in enumerate(idxs)},
-                            h, subset=subset, executor=executor)
+        def branch(idxs: Sequence[int], h: jax.Array) -> jax.Array:
+            sub = [layers[i] for i in idxs]
+            pln = _resolve_plan(sub, None, scheme, code, n, sys_params)
+            if pln is None:
+                with span("model.local"):
+                    return _local_layers(sub, [convs[i] for i in idxs], h)
+            return forward_plan(pln,
+                                {i: convs[j] for i, j in enumerate(idxs)},
+                                h, subset=subset, executor=executor)
 
-    h = _finish_layer(conv2d(_pad_hw(x, layers[0].pad), convs[0],
-                             layers[0].spec.stride), layers[0])
-    for c1, c2, ds in _resnet_blocks(layers):
-        skip = h if ds is None else conv2d(_pad_hw(h, layers[ds].pad),
-                                           convs[ds], layers[ds].spec.stride)
-        h = jax.nn.relu(branch((c1, c2), h) + skip)
-    h = h.reshape(h.shape[0], -1)
-    return h @ params["head"]
+        with span("model.local"):
+            h = _local_layers(layers[:1], convs[:1], x)
+        for c1, c2, ds in _resnet_blocks(layers):
+            skip = h
+            if ds is not None:
+                with span("model.local"):
+                    skip = conv2d(_pad_hw(h, layers[ds].pad), convs[ds],
+                                  layers[ds].spec.stride)
+            y = branch((c1, c2), h)
+            with span("model.local"):
+                h = jax.nn.relu(y + skip)
+        return _head(h, params["head"])
