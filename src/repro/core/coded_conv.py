@@ -31,6 +31,7 @@ Three execution modes:
 from __future__ import annotations
 
 import contextlib
+import functools
 import math
 from typing import Callable, Sequence
 
@@ -135,6 +136,63 @@ def _encode_partitions(code: CodingScheme, parts: jax.Array) -> jax.Array:
     return coded.reshape((code.n,) + parts.shape[1:])
 
 
+# The entry encode of a coded segment (run_segment) as compiled programs
+# with static slice bounds: eagerly, each static slice ``x[..., a:b]``
+# becomes a dynamic_slice whose start indices cross to the device, and the
+# stack, flatten, unflatten and per-piece selections are programs of
+# their own.  The encode GEMM stays the scheme's own program between them.
+
+@functools.partial(jax.jit, static_argnames="bounds")
+def _column_slices(x: jax.Array, bounds: tuple[tuple[int, int], ...]
+                   ) -> tuple[jax.Array, ...]:
+    """``x[..., a:b]`` for each ``(a, b)`` of ``bounds``, in one program."""
+    return tuple(x[..., a:b] for a, b in bounds)
+
+
+@functools.partial(jax.jit, static_argnames="bounds")
+def _source_rows(x: jax.Array, bounds: tuple[tuple[int, int], ...]
+                 ) -> jax.Array:
+    """The k equal-width column slices of ``bounds``, stacked and
+    flattened: the (k, F) source matrix of the encode (eq. 3)."""
+    return jnp.stack(_column_slices(x, bounds)).reshape(len(bounds), -1)
+
+
+@functools.partial(jax.jit, static_argnames="shape")
+def _coded_pieces(coded: jax.Array, shape: tuple[int, ...]
+                  ) -> tuple[jax.Array, ...]:
+    """(n, F) coded rows -> the n pieces, each of ``shape``."""
+    pieces = coded.reshape((coded.shape[0],) + shape)
+    return tuple(pieces[i] for i in range(pieces.shape[0]))
+
+
+def _bounds(chains: Sequence[ChainPlan]) -> tuple[tuple[int, int], ...]:
+    return tuple((cp.entry.a_i, cp.entry.b_i) for cp in chains)
+
+
+def _piece_chains(scheme: CodingScheme, split: SegmentSplitPlan
+                  ) -> list[ChainPlan]:
+    """The chain each of the n pieces runs.  A selection piece carries its
+    source partition's slice verbatim (edge chains are narrower); a linear
+    mix's pieces all share partition 0's geometry."""
+    if commutes_elementwise(scheme):
+        return [split.parts[source_of_piece(scheme, i)]
+                for i in range(scheme.n)]
+    return [split.parts[0]] * scheme.n
+
+
+def _entry_pieces(x: jax.Array, scheme: CodingScheme,
+                  split: SegmentSplitPlan) -> tuple[jax.Array, ...]:
+    """The n pieces' entry inputs: a selection scheme's are its source
+    partitions' column slices (one program); a linear mix's are its encode
+    of the k partitions (the stacked slices, the scheme's encode GEMM, the
+    split into pieces).  No slice index crosses to the device."""
+    if commutes_elementwise(scheme):
+        return _column_slices(x, _bounds(_piece_chains(scheme, split)))
+    p0 = split.parts[0].entry
+    coded = scheme.encode(_source_rows(x, _bounds(split.parts)))
+    return _coded_pieces(coded, x.shape[:-1] + (p0.b_i - p0.a_i,))
+
+
 def coded_conv2d(
     x: jax.Array,
     w: jax.Array,
@@ -164,7 +222,8 @@ def coded_conv2d(
         plan = plan_width_split(spec, code.k)
     # backend seam (dist/backend.py): the backend owns encode -> per-piece
     # conv -> decode (the mesh backend fuses them into one shard_map
-    # program; the thread pool encodes eagerly and thunks)
+    # program; the thread pool encodes on the master and thunks the
+    # pieces; run_segment's entry encode is the compiled one)
     seam = executor is not None and hasattr(executor, "run_op")
     p0 = plan.parts[0]
     with _encode_span(code, code.n * math.prod(x.shape[:-1])
@@ -272,6 +331,9 @@ def run_segment(
     here: the master applies it after decode (with any pooling), keeping
     depth-1 segments numerically identical to ``coded_conv2d``.
 
+    The entry encode runs as compiled programs with static slice bounds
+    (``_entry_pieces``) and counts ``encodes_compiled``.
+
     Functional form computes all n chains; with ``executor`` (a
     ``repro.dist.CodedExecutor``) each chain is one multi-layer piece on
     the worker pool, decoded at the k-th *arrival* with straggler
@@ -299,24 +361,12 @@ def run_segment(
                 " that a linear mix cannot represent piece-locally — only "
                 "selection schemes (replication/uncoded) may fuse across it")
 
-    if commuting:
-        # selection dispatch: piece i carries its source partition's slice
-        # verbatim (edge chains are narrower — no row-stacking involved)
-        piece_part = [split.parts[source_of_piece(scheme, i)]
-                      for i in range(scheme.n)]
-    else:
-        piece_part = [split.parts[0]] * scheme.n
+    piece_part = _piece_chains(scheme, split)
     width = sum(cp.entry.b_i - cp.entry.a_i for cp in piece_part)
     with _encode_span(scheme, width * math.prod(x.shape[:-1])
                       * x.dtype.itemsize):
-        if commuting:
-            piece_in = [x[..., cp.entry.a_i:cp.entry.b_i]
-                        for cp in piece_part]
-        else:
-            parts = jnp.stack(
-                [x[..., cp.entry.a_i:cp.entry.b_i] for cp in split.parts])
-            coded_in = _encode_partitions(scheme, parts)
-            piece_in = [coded_in[i] for i in range(scheme.n)]
+        piece_in = _entry_pieces(x, scheme, split)
+        count("encodes_compiled")
     chunks = max(1, int(stream_chunks)) if stream_chunks else 1
 
     def _piece(i: int) -> jax.Array:

@@ -31,6 +31,7 @@ __all__ = [
     "vandermonde_nodes",
     "vandermonde_generator",
     "decode_matrix_cached",
+    "device_matrix",
     "MDSCode",
     "ReplicationCode",
     "LTCode",
@@ -81,6 +82,16 @@ def decode_matrix_cached(n: int, k: int, subset: tuple, kind: str) -> np.ndarray
     return D
 
 
+@functools.lru_cache(maxsize=512)
+def device_matrix(make, args: tuple, dtype) -> jax.Array:
+    """``jnp.asarray(make(*args), dtype)``, made once per key: the device
+    copy of an encode matrix (``vandermonde_generator``, the LT rows), so
+    an encode transfers nothing.  Made outside any trace, so the cached
+    array is concrete even when first asked for inside a jit."""
+    with jax.ensure_compile_time_eval():
+        return jnp.asarray(make(*args), dtype=dtype)
+
+
 @dataclasses.dataclass(frozen=True)
 class MDSCode:
     """(n, k) MDS code over f32/f64 with a Vandermonde generator (eq. 3/4)."""
@@ -126,7 +137,8 @@ class MDSCode:
             raise ValueError(f"expected {self.k} source rows, got {sources.shape[0]}")
         from ..kernels.ops import mds_encode
 
-        G = jnp.asarray(self.generator, dtype=sources.dtype)
+        G = device_matrix(vandermonde_generator,
+                          (self.n, self.k, self.node_kind), sources.dtype)
         return mds_encode(G, sources)
 
     # -- decode -----------------------------------------------------------

@@ -44,7 +44,7 @@ from typing import Callable, Protocol, Sequence, runtime_checkable
 
 import numpy as np
 
-from .coding import LTCode, MDSCode, ReplicationCode
+from .coding import LTCode, MDSCode, ReplicationCode, device_matrix
 from .splitting import ConvSpec
 
 __all__ = [
@@ -716,11 +716,10 @@ class LTScheme:
         """(k, F) -> (n, F) through the same Pallas skinny-GEMM as MDS."""
         if sources.shape[0] != self.k:
             raise ValueError(f"expected {self.k} source rows, got {sources.shape[0]}")
-        import jax.numpy as jnp
-
         from ..kernels.ops import mds_encode
 
-        E = jnp.asarray(self.rows, dtype=sources.dtype)
+        E = device_matrix(_lt_rows, (self.n, self.k, self.seed, self.c,
+                                     self.delta), sources.dtype)
         return mds_encode(E, sources)
 
     def decode_from(self, subset: Sequence[int], coded):
