@@ -54,6 +54,7 @@ __all__ = [
     "run_segment",
     "boundary_op_counter",
     "ACTIVATIONS",
+    "add_bias",
 ]
 
 
@@ -92,17 +93,28 @@ def _encode_span(scheme: CodingScheme, nbytes: int) -> span:
 
 ACTIVATIONS: dict[str, Callable[[jax.Array], jax.Array]] = {
     "relu": jax.nn.relu,
-    "gelu": jax.nn.gelu,
+    # the exact (erf) form, as ConvNeXt and the original GELU define it
+    "gelu": functools.partial(jax.nn.gelu, approximate=False),
     "silu": jax.nn.silu,
 }
 
 
 def conv2d(x: jax.Array, w: jax.Array, stride: int = 1) -> jax.Array:
-    """Plain VALID conv (input is pre-padded, as in the paper). NCHW/OIHW."""
+    """Plain VALID conv (input is pre-padded, as in the paper). NCHW/OIHW.
+
+    The channel groups follow from the shapes: an OIHW weight whose ``I``
+    is ``C_in / g`` makes a grouped conv of g groups (``I == 1``: depthwise);
+    a dense weight gives g = 1, the plain conv."""
     return jax.lax.conv_general_dilated(
         x, w, window_strides=(stride, stride), padding="VALID",
         dimension_numbers=("NCHW", "OIHW", "NCHW"),
+        feature_group_count=x.shape[1] // w.shape[1],
     )
+
+
+def add_bias(y: jax.Array, b: jax.Array | None) -> jax.Array:
+    """Add a per-output-channel bias to an NCHW conv output (None: none)."""
+    return y if b is None else y + b[:, None, None]
 
 
 def conv2d_chunked(x: jax.Array, w: jax.Array, stride: int = 1,
@@ -272,21 +284,24 @@ def coded_conv2d(
 
 def _chain(xp: jax.Array, cp: ChainPlan, weights: Sequence[jax.Array],
            specs: Sequence[ConvSpec], pads: Sequence[int],
-           acts: Sequence[str | None], apply_acts: bool,
-           entry_chunks: int = 1) -> jax.Array:
+           acts: Sequence[str | None], biases: Sequence[jax.Array | None],
+           apply_acts: bool, entry_chunks: int = 1) -> jax.Array:
     """Run one partition's self-contained conv chain on its (coded or true)
-    entry slice.  Interior boundaries re-apply the activation (when
-    ``apply_acts``) and inject the re-pad: full zero rows on H, and on W
-    only the per-partition edge shortfall (``ChainStep.lz``/``rz``) — the
-    interior halo columns are real data already resident in the slice.
-    ``entry_chunks > 1`` tiles layer 0's conv over output-column blocks
-    (streamed entry: compute starts on the first shipped chunk) — identical
-    values, tiled evaluation order."""
+    entry slice.  Interior boundaries re-apply the bias and the activation
+    (when ``apply_acts``: the slice holds true values) and inject the
+    re-pad: full zero rows on H, and on W only the per-partition edge
+    shortfall (``ChainStep.lz``/``rz``) — the interior halo columns are
+    real data already resident in the slice.  ``entry_chunks > 1`` tiles
+    layer 0's conv over output-column blocks (streamed entry: compute
+    starts on the first shipped chunk) — identical values, tiled
+    evaluation order."""
     for j, (w, sp) in enumerate(zip(weights, specs)):
         if j > 0:
             st = cp.steps[j]
-            if apply_acts and acts[j - 1] is not None:
-                xp = ACTIVATIONS[acts[j - 1]](xp)
+            if apply_acts:
+                xp = add_bias(xp, biases[j - 1])
+                if acts[j - 1] is not None:
+                    xp = ACTIVATIONS[acts[j - 1]](xp)
             p = int(pads[j])
             if p or st.lz or st.rz:
                 xp = jnp.pad(xp, ((0, 0), (0, 0), (p, p), (st.lz, st.rz)))
@@ -308,6 +323,7 @@ def run_segment(
     executor=None,
     assignment: Sequence[int] | None = None,
     stream_chunks: int | None = None,
+    biases: Sequence[jax.Array | None] | None = None,
 ) -> jax.Array:
     """Execute a coded *segment*: encode once, per-piece conv chains, decode
     once (core/netplan.py's execution form).
@@ -330,6 +346,10 @@ def run_segment(
     silently producing wrong output.  The final activation is NOT applied
     here: the master applies it after decode (with any pooling), keeping
     depth-1 segments numerically identical to ``coded_conv2d``.
+    ``biases[j]`` (None: no bias) is layer j's per-channel bias, affine
+    as an activation is elementwise: the code mixes only the linear conv,
+    interior biases run in the chains under selection schemes only, and
+    the last layer's is the master's, after decode.
 
     The entry encode runs as compiled programs with static slice bounds
     (``_entry_pieces``) and counts ``encodes_compiled``.
@@ -340,21 +360,26 @@ def run_segment(
     cancellation at segment granularity.
     """
     d = len(specs)
-    if not (len(weights) == len(pads) == len(acts) == d):
+    if biases is None:
+        biases = [None] * d
+    if not (len(weights) == len(pads) == len(acts) == len(biases) == d):
         raise ValueError(f"inconsistent segment arity: {len(weights)} weights"
-                         f", {d} specs, {len(pads)} pads, {len(acts)} acts")
+                         f", {d} specs, {len(pads)} pads, {len(acts)} acts, "
+                         f"{len(biases)} biases")
     if split is None:
         split = plan_segment_split(specs, pads, scheme.k)
     if split.k != scheme.k:
         raise ValueError(f"split.k={split.k} != scheme.k={scheme.k}")
     commuting = commutes_elementwise(scheme)
     if not commuting and d > 1:
-        if any(a is not None for a in acts[:-1]):
+        if any(a is not None for a in acts[:-1]) or any(
+                b is not None for b in biases[:-1]):
             raise ValueError(
                 f"scheme {getattr(scheme, 'scheme_name', scheme)} is a "
-                "linear mix: relu(G x) != G relu(x), so pieces cannot stay "
-                "resident across an interior activation — recompile with a "
-                "decode point there (netplan places it automatically)")
+                "linear mix: relu(G x) != G relu(x) and G x + b != G (x + b),"
+                " so pieces cannot stay resident across an interior "
+                "activation or bias — recompile with a decode point there "
+                "(netplan places it automatically)")
         if any(int(p) != 0 for p in pads[1:]) or not split.uniform:
             raise ValueError(
                 "interior re-padding injects partition-dependent edge zeros"
@@ -371,7 +396,7 @@ def run_segment(
 
     def _piece(i: int) -> jax.Array:
         return _chain(piece_in[i], piece_part[i], weights, specs, pads, acts,
-                      apply_acts=commuting, entry_chunks=chunks)
+                      biases, apply_acts=commuting, entry_chunks=chunks)
 
     if executor is not None:
         if hasattr(executor, "ensure_armed"):
@@ -404,7 +429,8 @@ def run_segment(
         with span("model.remainder"):
             y_rem = _chain(
                 x[..., split.remainder.entry.a_i:split.remainder.entry.b_i],
-                split.remainder, weights, specs, pads, acts, apply_acts=True)
+                split.remainder, weights, specs, pads, acts, biases,
+                apply_acts=True)
             y = jnp.concatenate([y, y_rem], axis=-1)
     return y
 

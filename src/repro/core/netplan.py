@@ -77,10 +77,10 @@ class LayerInfo:
     """One conv layer of a network, with its execution-relevant structure.
 
     ``act`` is the elementwise activation applied after the conv (None for
-    a purely linear layer), ``pad`` the symmetric zero-pad applied to this
-    layer's input (the spec's ``w_in``/``h_in`` are the padded sizes), and
-    ``pool`` the max-pool window (== stride) applied after the activation
-    (0 = none).  The paper's type-1/type-2 classification (App. A) rides
+    a purely linear layer), ``bias`` whether a per-channel bias is added
+    before it, ``pad`` the symmetric zero-pad applied to this layer's input
+    (the spec's ``w_in``/``h_in`` are the padded sizes), and ``pool`` the
+    max-pool window (== stride) applied after the activation (0 = none).  The paper's type-1/type-2 classification (App. A) rides
     in ``type1``.
     """
 
@@ -100,6 +100,7 @@ class LayerInfo:
     # per-layer drift can move a segment boundary, not just k°.  1.0 =
     # trust the baseline.
     cmp_scale: float = 1.0
+    bias: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -416,10 +417,11 @@ def _fusible(prev: LayerInfo, cur: LayerInfo, commuting: bool) -> bool:
         return False
     if cs.w_in != ps.w_out + 2 * p or cs.h_in != ps.h_out + 2 * p:
         return False  # geometry does not chain
-    if not commuting and (prev.act is not None or p != 0):
-        # linear mixes cannot cross an elementwise activation, and the
-        # interior re-pad's edge zeros are partition-dependent — both
-        # force a decode point for non-selection schemes
+    if not commuting and (prev.act is not None or prev.bias or p != 0):
+        # linear mixes cannot cross an elementwise activation or a bias
+        # (G x + b != G (x + b)), and the interior re-pad's edge zeros are
+        # partition-dependent — each forces a decode point for
+        # non-selection schemes
         return False
     return True
 

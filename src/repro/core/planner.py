@@ -59,7 +59,7 @@ def _sizes_continuous(spec: ConvSpec, n: int, k: float) -> PhaseSizes:
     row_out = spec.batch * spec.c_out * spec.h_out * w_o_p
     return PhaseSizes(
         n_enc=2.0 * k * n * row_in,
-        n_cmp=spec.batch * spec.c_out * spec.h_out * w_o_p * 2 * spec.c_in * spec.kernel ** 2,
+        n_cmp=spec.subtask_flops(w_o_p),
         n_rec=4.0 * row_in,
         n_sen=4.0 * row_out,
         n_dec=2.0 * k * k * row_out,
@@ -326,7 +326,8 @@ def straggling_index_R(spec: ConvSpec, params: SystemParams) -> float:
     """R of §IV-C — smaller R = stronger straggling; Prop. 2 needs R <= 1."""
     I_W = spec.c_in * spec.h_in * spec.w_out * spec.stride
     O = spec.c_out * spec.h_out * spec.w_out
-    N_cmp = 2 * spec.c_out * spec.h_out * spec.c_in * spec.kernel ** 2 * spec.w_out
+    N_cmp = (2 * spec.c_out * spec.h_out * (spec.c_in // spec.groups)
+             * spec.kernel ** 2 * spec.w_out)
     num = 4 * I_W * params.theta_rec + 4 * O * params.theta_sen + N_cmp * params.theta_cmp
     den = 4 * I_W / params.mu_rec + 4 * O / params.mu_sen + N_cmp / params.mu_cmp
     return num / den

@@ -53,6 +53,10 @@ class ConvSpec:
     """Geometry of a 2D conv layer (paper Table II).
 
     Width/height of the *padded* input I; kernel/stride on the width dim.
+    ``groups`` splits the channels into that many independent convs (a
+    depthwise conv has ``groups == c_in``): each output channel reads
+    ``c_in // groups`` input channels, which divides the FLOPs but not the
+    bytes.
     """
 
     c_in: int
@@ -62,6 +66,13 @@ class ConvSpec:
     kernel: int  # K_W (square kernel)
     stride: int = 1
     batch: int = 1
+    groups: int = 1
+
+    def __post_init__(self):
+        if self.groups < 1 or self.c_in % self.groups \
+                or self.c_out % self.groups:
+            raise ValueError(f"groups={self.groups} must divide c_in="
+                             f"{self.c_in} and c_out={self.c_out}")
 
     @property
     def w_out(self) -> int:
@@ -73,9 +84,8 @@ class ConvSpec:
 
     def subtask_flops(self, w_out_p: int) -> int:
         """N^cmp(k) of eq. (9) for an output slice of width w_out_p."""
-        return (
-            self.batch * self.c_out * self.h_out * w_out_p * 2 * self.c_in * self.kernel ** 2
-        )
+        return (self.batch * self.c_out * self.h_out * w_out_p * 2
+                * (self.c_in // self.groups) * self.kernel ** 2)
 
     def recv_bytes(self, w_in_p: int) -> int:
         """N^rec(k) of eq. (10): f32 bytes of one input partition."""

@@ -1,4 +1,5 @@
-"""CNN workloads from the paper (§V, App. A): VGG16, ResNet18, small CNN.
+"""CNN workloads: the paper's (§V, App. A: VGG16, ResNet18, a small CNN)
+and ConvNeXt (Liu et al., arXiv:2201.03545).
 
 Three artefacts per network:
 
@@ -28,7 +29,7 @@ from typing import Callable, List, Sequence
 import jax
 import jax.numpy as jnp
 
-from ..core.coded_conv import ACTIVATIONS, conv2d, run_segment
+from ..core.coded_conv import ACTIVATIONS, add_bias, conv2d, run_segment
 from ..core.latency import SystemParams
 from ..core.netplan import (LayerInfo, LocalStep, NetPlan, SegmentStep,
                             compile_plan)
@@ -42,7 +43,8 @@ __all__ = ["LayerInfo", "vgg16_conv_specs", "resnet18_conv_specs",
            "init_small_cnn", "small_cnn_forward", "small_cnn_conv_specs",
            "small_cnn_layers", "SMALL_CNN_PARAMS",
            "init_vgg16", "vgg16_forward",
-           "init_resnet18", "resnet18_forward"]
+           "init_resnet18", "resnet18_forward",
+           "convnext_conv_specs", "init_convnext", "convnext_forward"]
 
 
 # ---------------------------------------------------------------------------
@@ -173,7 +175,9 @@ def _pad_hw(x: jax.Array, pad: int) -> jax.Array:
     return jnp.pad(x, ((0, 0), (0, 0), (pad, pad), (pad, pad)))
 
 
-def _finish_layer(y: jax.Array, li: LayerInfo) -> jax.Array:
+def _finish_layer(y: jax.Array, li: LayerInfo,
+                  bias: jax.Array | None = None) -> jax.Array:
+    y = add_bias(y, bias)
     if li.act is not None:
         y = ACTIVATIONS[li.act](y)
     if li.pool:
@@ -182,19 +186,24 @@ def _finish_layer(y: jax.Array, li: LayerInfo) -> jax.Array:
 
 
 def forward_plan(plan: NetPlan, convs: Sequence[jax.Array], x: jax.Array,
-                 *, subset=None, executor=None,
-                 assignment=None) -> jax.Array:
+                 *, subset=None, executor=None, assignment=None,
+                 biases=None) -> jax.Array:
     """Run a conv stack through its compiled plan.
 
     Segments execute ``run_segment`` (one encode, resident chains, one
-    decode; interior activations inside the chains); the master applies
-    each segment's final activation and pooling post-decode, and runs
-    LocalStep layers itself (``model.local`` spans, as is the segment's
-    entry pad).  ``convs[i]`` is layer i's OIHW weight.
+    decode; interior biases and activations inside the chains); the master
+    applies each segment's final bias, activation and pooling post-decode,
+    and runs LocalStep layers itself (``model.local`` spans, as is the
+    segment's entry pad).  ``convs[i]`` is layer i's OIHW weight and
+    ``biases[i]`` its bias (``biases`` None: no layer has one).
     """
+    if biases is None:
+        biases = [None] * len(plan.layers)
     for step in plan.steps:
         sub = plan.layers[step.start:step.stop]
-        ws = [convs[i] for i in range(step.start, step.stop)]
+        idx = range(step.start, step.stop)
+        ws = [convs[i] for i in idx]
+        bs = [biases[i] for i in idx]
         if isinstance(step, SegmentStep):
             with span("model.local"):
                 xp = _pad_hw(x, sub[0].pad)
@@ -202,20 +211,21 @@ def forward_plan(plan: NetPlan, convs: Sequence[jax.Array], x: jax.Array,
                 xp, ws, step.scheme,
                 [li.spec for li in sub], [li.pad for li in sub],
                 [li.act for li in sub], split=step.split, subset=subset,
-                executor=executor, assignment=assignment)
+                executor=executor, assignment=assignment, biases=bs)
             with span("model.local"):
-                x = _finish_layer(y, sub[-1])
+                x = _finish_layer(y, sub[-1], bs[-1])
         else:
             with span("model.local"):
-                x = _local_layers(sub, ws, x)
+                x = _local_layers(sub, ws, x, bs)
     return x
 
 
-def _local_layers(layers: Sequence[LayerInfo], convs, x: jax.Array
-                  ) -> jax.Array:
-    """Run conv layers (pad, conv, activation, pool) on the master."""
-    for li, w in zip(layers, convs):
-        x = _finish_layer(conv2d(_pad_hw(x, li.pad), w, li.spec.stride), li)
+def _local_layers(layers: Sequence[LayerInfo], convs, x: jax.Array,
+                  biases=None) -> jax.Array:
+    """Run conv layers (pad, conv, bias, activation, pool) on the master."""
+    for i, (li, w) in enumerate(zip(layers, convs)):
+        x = _finish_layer(conv2d(_pad_hw(x, li.pad), w, li.spec.stride), li,
+                          None if biases is None else biases[i])
     return x
 
 
@@ -456,3 +466,168 @@ def resnet18_forward(
             with span("model.local"):
                 h = jax.nn.relu(y + skip)
         return _head(h, params["head"])
+
+
+# ---------------------------------------------------------------------------
+# runnable ConvNeXt (Liu et al., arXiv:2201.03545)
+# ---------------------------------------------------------------------------
+
+CONVNEXT_B = {"widths": (128, 256, 512, 1024), "depths": (3, 3, 27, 3)}
+CONVNEXT_NORM_EPS = 1e-6
+
+
+def convnext_conv_specs(image: int = 224,
+                        widths: Sequence[int] = CONVNEXT_B["widths"],
+                        depths: Sequence[int] = CONVNEXT_B["depths"],
+                        params: SystemParams | None = None
+                        ) -> List[LayerInfo]:
+    """Every conv of a ConvNeXt, in order; each has a bias.
+
+    The stem is a 4x4 stride-4 conv; stages 2-4 open with a 2x2 stride-2
+    downsample (``ds<s>``); a block (``s<s>b<b>``) is a 7x7 depthwise conv
+    (``dw``, groups = C), then the pointwise convs ``pw1`` (C -> 4C, GELU)
+    and ``pw2`` (4C -> C) as 1x1 convs.  A LayerNorm or the residual join
+    follows every conv but ``pw1``: those are barriers, so only the
+    pointwise pair may share a segment (under selection schemes; linear
+    mixes decode at the GELU and the bias)."""
+    out: List[LayerInfo] = []
+
+    def add(name, ci, co, size, kernel=1, stride=1, pad=0, groups=1,
+            act=None, barrier=True):
+        spec = ConvSpec(c_in=ci, c_out=co, h_in=size + 2 * pad,
+                        w_in=size + 2 * pad, kernel=kernel, stride=stride,
+                        groups=groups)
+        out.append(LayerInfo(name, spec, is_type1(spec, params), act=act,
+                             pad=pad, barrier=barrier, bias=True))
+
+    add("stem", 3, widths[0], image, kernel=4, stride=4)
+    size = image // 4
+    for s, (c, depth) in enumerate(zip(widths, depths), start=1):
+        if s > 1:
+            add(f"ds{s}", widths[s - 2], c, size, kernel=2, stride=2)
+            size //= 2
+        for b in range(depth):
+            add(f"s{s}b{b}dw", c, c, size, kernel=7, pad=3, groups=c)
+            add(f"s{s}b{b}pw1", c, 4 * c, size, act="gelu", barrier=False)
+            add(f"s{s}b{b}pw2", 4 * c, c, size)
+    return out
+
+
+@jax.jit
+def layer_norm(x: jax.Array, scale: jax.Array, shift: jax.Array
+               ) -> jax.Array:
+    """LayerNorm over axis 1 (the channels of NCHW, the features of (B, F)),
+    eps 1e-6, then the per-channel affine; one program per call."""
+    mean = x.mean(axis=1, keepdims=True)
+    var = jnp.square(x - mean).mean(axis=1, keepdims=True)
+    shape = (-1,) + (1,) * (x.ndim - 2)
+    return ((x - mean) * jax.lax.rsqrt(var + CONVNEXT_NORM_EPS)
+            * scale.reshape(shape) + shift.reshape(shape))
+
+
+def _norm(x: jax.Array, p: dict) -> jax.Array:
+    with span("model.norm", channels=x.shape[1]):
+        return layer_norm(x, p["scale"], p["shift"])
+
+
+def init_convnext(key: jax.Array, n_classes: int = 1000, image: int = 224,
+                  widths: Sequence[int] = CONVNEXT_B["widths"],
+                  depths: Sequence[int] = CONVNEXT_B["depths"]) -> dict:
+    """Seeded weights in ``convnext_forward``'s layout: He-normal convs,
+    biases and LayerNorm affines near 0 and 1, layer scales of order 1
+    (not the training init of 1e-6, under which every block's branch
+    would vanish), a head N(0, 1/F)."""
+    layers = convnext_conv_specs(image, widths, depths)
+    k_conv, k_rest = jax.random.split(key)
+    convs = {}
+    for k, li in zip(jax.random.split(k_conv, len(layers)), layers):
+        s = li.spec
+        fan_in = s.c_in // s.groups * s.kernel ** 2
+        kw, kb = jax.random.split(k)
+        convs[li.name] = {
+            "w": jax.random.normal(kw, (s.c_out, s.c_in // s.groups,
+                                        s.kernel, s.kernel), jnp.float32)
+            * (2.0 / fan_in) ** 0.5,
+            "b": 0.1 * jax.random.normal(kb, (s.c_out,), jnp.float32)}
+    keys = iter(jax.random.split(k_rest, 3 * len(layers) + 3))
+
+    def norm(c):
+        return {"scale": 1.0 + 0.1 * jax.random.normal(next(keys), (c,)),
+                "shift": 0.1 * jax.random.normal(next(keys), (c,))}
+
+    stages = []
+    for s, (c, depth) in enumerate(zip(widths, depths), start=1):
+        blocks = [{"dw": convs[f"s{s}b{b}dw"], "norm": norm(c),
+                   "pw1": convs[f"s{s}b{b}pw1"], "pw2": convs[f"s{s}b{b}pw2"],
+                   "gamma": jax.random.uniform(next(keys), (c,),
+                                               minval=0.5, maxval=1.5)}
+                  for b in range(depth)]
+        down = (None if s == 1 else
+                dict(convs[f"ds{s}"], norm=norm(widths[s - 2])))
+        stages.append({"down": down, "blocks": blocks})
+    c = widths[-1]
+    return {"stem": dict(convs["stem"], norm=norm(widths[0])),
+            "stages": stages,
+            "head": {"norm": norm(c),
+                     "w": jax.random.normal(next(keys), (c, n_classes))
+                     * c ** -0.5,
+                     "b": 0.1 * jax.random.normal(next(keys), (n_classes,))}}
+
+
+def convnext_forward(
+    params: dict,
+    x: jax.Array,
+    code: CodingScheme | None = None,
+    subset=None,
+    *,
+    scheme: str | CodingScheme | None = None,
+    n: int | None = None,
+    sys_params: SystemParams | None = None,
+    executor=None,
+) -> jax.Array:
+    """Runnable ConvNeXt (``init_convnext``'s layout; the widths and depths
+    are read from it).  Drop path is the identity at inference.
+
+    Every conv runs through a mini plan of its own, as ResNet18's branches
+    do, and each block's pointwise pair through one: the convs take the
+    coded path wherever the plan codes them, and their biases are added
+    after decode.  The LayerNorms (``model.norm``), the depthwise convs
+    the plan keeps local (``model.dwconv``, with their pad and bias), the
+    layer scale and residual add, the global average pool and the head
+    (``model.local``) run on the master.
+    """
+    with span("model.forward", batch=x.shape[0]):
+        stages = params["stages"]
+        widths = [st["blocks"][0]["dw"]["w"].shape[0] for st in stages]
+        depths = [len(st["blocks"]) for st in stages]
+        layers = {li.name: li for li in convnext_conv_specs(
+            x.shape[-1], widths, depths, sys_params)}
+
+        def run(names, convs, h, local_span="model.local"):
+            sub = [layers[name] for name in names]
+            ws = [p["w"] for p in convs]
+            bs = [p["b"] for p in convs]
+            pln = _resolve_plan(sub, None, scheme, code, n, sys_params)
+            if pln is None or not pln.segments:
+                with span(local_span):
+                    return _local_layers(sub, ws, h, bs)
+            return forward_plan(pln, ws, h, biases=bs, subset=subset,
+                                executor=executor)
+
+        h = _norm(run(["stem"], [params["stem"]], x), params["stem"]["norm"])
+        for s, st in enumerate(stages, start=1):
+            if st["down"] is not None:
+                h = run([f"ds{s}"], [st["down"]], _norm(h, st["down"]["norm"]))
+            for b, blk in enumerate(st["blocks"]):
+                y = run([f"s{s}b{b}dw"], [blk["dw"]], h,
+                        local_span="model.dwconv")
+                y = run([f"s{s}b{b}pw1", f"s{s}b{b}pw2"],
+                        [blk["pw1"], blk["pw2"]], _norm(y, blk["norm"]))
+                with span("model.local"):
+                    h = h + blk["gamma"][:, None, None] * y
+        head = params["head"]
+        with span("model.local"):
+            h = h.mean(axis=(2, 3))
+        h = _norm(h, head["norm"])
+        with span("model.local"):
+            return h @ head["w"] + head["b"]
