@@ -41,7 +41,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..telemetry.trace import count, counting, span
 from .schemes import (CodingScheme, chunk_bounds, commutes_elementwise,
-                      decode_blocks, resolve_subset, source_of_piece)
+                      decode_pieces, resolve_subset, source_of_piece)
 from .splitting import (ChainPlan, ConvSpec, SegmentSplitPlan, SplitPlan,
                         plan_segment_split, plan_width_split)
 
@@ -177,6 +177,13 @@ def _coded_pieces(coded: jax.Array, shape: tuple[int, ...]
     return tuple(pieces[i] for i in range(pieces.shape[0]))
 
 
+@jax.jit
+def _join_parts(parts: jax.Array) -> jax.Array:
+    """The k decoded parts ``(k, B, C, H, W_p)`` side by side along W:
+    ``(B, C, H, k * W_p)`` in one program (the segment exit's join)."""
+    return jnp.concatenate(list(parts), axis=-1)
+
+
 def _bounds(chains: Sequence[ChainPlan]) -> tuple[tuple[int, int], ...]:
     return tuple((cp.entry.a_i, cp.entry.b_i) for cp in chains)
 
@@ -273,7 +280,7 @@ def coded_conv2d(
 
     # Reassemble on the width dim; master-kept remainder (footnote 2).
     with span("model.decode", n=code.n, k=code.k):
-        y = jnp.concatenate(list(y_parts), axis=-1)
+        y = _join_parts(y_parts)
     if plan.remainder is not None:
         with span("model.remainder"):
             r = plan.remainder
@@ -352,7 +359,11 @@ def run_segment(
     the last layer's is the master's, after decode.
 
     The entry encode runs as compiled programs with static slice bounds
-    (``_entry_pieces``) and counts ``encodes_compiled``.
+    (``_entry_pieces``) and counts ``encodes_compiled``.  The exit decode
+    does too: the stack and flatten of the arrived pieces, the scheme's
+    decode GEMM, the unflatten (``schemes.decode_pieces``, in the
+    executor's ``result`` or here) and the join along W (``_join_parts``);
+    it counts ``decodes_compiled``.
 
     Functional form computes all n chains; with ``executor`` (a
     ``repro.dist.CodedExecutor``) each chain is one multi-layer piece on
@@ -417,12 +428,12 @@ def run_segment(
             outs = [_piece(i) for i in subset]
         with span("model.decode", n=scheme.n, k=scheme.k,
                   pieces=len(subset)):
-            y_parts = decode_blocks(scheme, subset, jnp.stack(outs),
-                                    chunks=chunks)
+            y_parts = decode_pieces(scheme, subset, outs, chunks=chunks)
     count("decodes")
+    count("decodes_compiled")
 
     with span("model.decode", n=scheme.n, k=scheme.k):
-        y = jnp.concatenate(list(y_parts), axis=-1)
+        y = _join_parts(y_parts)
     if split.remainder is not None:
         # footnote 2 at segment granularity: the master runs the remainder
         # columns' whole chain locally, on true values (acts always apply)

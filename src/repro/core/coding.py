@@ -32,6 +32,7 @@ __all__ = [
     "vandermonde_generator",
     "decode_matrix_cached",
     "device_matrix",
+    "take_rows",
     "MDSCode",
     "ReplicationCode",
     "LTCode",
@@ -85,11 +86,19 @@ def decode_matrix_cached(n: int, k: int, subset: tuple, kind: str) -> np.ndarray
 @functools.lru_cache(maxsize=512)
 def device_matrix(make, args: tuple, dtype) -> jax.Array:
     """``jnp.asarray(make(*args), dtype)``, made once per key: the device
-    copy of an encode matrix (``vandermonde_generator``, the LT rows), so
-    an encode transfers nothing.  Made outside any trace, so the cached
+    copy of an encode matrix (``vandermonde_generator``, the LT rows) or of
+    a decode matrix (keyed by its ordered subset), so neither an encode nor
+    a decode transfers anything.  Made outside any trace, so the cached
     array is concrete even when first asked for inside a jit."""
     with jax.ensure_compile_time_eval():
         return jnp.asarray(make(*args), dtype=dtype)
+
+
+@functools.partial(jax.jit, static_argnames="rows")
+def take_rows(x: jax.Array, rows: tuple[int, ...]) -> jax.Array:
+    """``x[rows]`` with the row indices static: a selection decode's gather
+    as one program whose indices never cross to the device."""
+    return jnp.stack([x[r] for r in rows])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -142,14 +151,18 @@ class MDSCode:
         return mds_encode(G, sources)
 
     # -- decode -----------------------------------------------------------
-    def decode_matrix(self, subset: Sequence[int]) -> np.ndarray:
-        """G_S^{-1} for the k-subset S of worker indices (eq. 4), cached."""
+    def _decode_key(self, subset: Sequence[int]) -> tuple:
+        """``decode_matrix_cached``'s key for the ordered k-subset S."""
         subset = tuple(int(i) for i in subset)
         if len(subset) != self.k:
             raise ValueError(f"need exactly k={self.k} indices, got {len(subset)}")
         if len(set(subset)) != self.k:
             raise ValueError("subset indices must be distinct")
-        return decode_matrix_cached(self.n, self.k, subset, self.node_kind)
+        return (self.n, self.k, subset, self.node_kind)
+
+    def decode_matrix(self, subset: Sequence[int]) -> np.ndarray:
+        """G_S^{-1} for the k-subset S of worker indices (eq. 4), cached."""
+        return decode_matrix_cached(*self._decode_key(subset))
 
     def decode_from(self, subset: Sequence[int], coded: jax.Array) -> jax.Array:
         """Recover (k, F) sources from the coded rows named by ``subset``.
@@ -157,7 +170,9 @@ class MDSCode:
         Any k rows suffice (eq. 4); a larger subset (the pipeline allows
         m > k for rateless schemes) is down-selected to its first k rows.
         The D @ Y GEMM runs through the Pallas decode kernel
-        (kernels/mds_decode.py), mirroring the encode path.
+        (kernels/mds_decode.py), mirroring the encode path; D is the
+        device copy kept per ordered subset and dtype (``device_matrix``),
+        so a decode transfers nothing.
         """
         from ..kernels.ops import mds_decode
 
@@ -174,8 +189,9 @@ class MDSCode:
                 if len(keep) == self.k:
                     break
             subset = [subset[p] for p in keep]
-            coded = coded[jnp.asarray(keep)]
-        D = jnp.asarray(self.decode_matrix(subset), dtype=coded.dtype)
+            coded = take_rows(coded, tuple(keep))
+        D = device_matrix(decode_matrix_cached, self._decode_key(subset),
+                          coded.dtype)
         return mds_decode(D, coded)
 
     # -- latency-model scaling (eqs. 8, 12) --------------------------------
@@ -229,8 +245,9 @@ class ReplicationCode:
             return False
         return len({i % self.k for i in idx}) == self.k
 
-    def decode_from(self, subset: Sequence[int], coded: jax.Array) -> jax.Array:
-        """Pick one received copy of each source row."""
+    def decode_positions(self, subset: Sequence[int]) -> list[int]:
+        """Positions in ``subset`` of one received copy of each source row,
+        in source order: the rows a selection decode keeps."""
         assign = self.assignment()
         chosen: dict[int, int] = {}
         for pos, widx in enumerate(subset):
@@ -238,8 +255,11 @@ class ReplicationCode:
             chosen.setdefault(src, pos)
         if len(chosen) != self.k:
             raise ValueError("subset does not cover all source rows")
-        order = [chosen[s] for s in range(self.k)]
-        return coded[jnp.asarray(order)]
+        return [chosen[s] for s in range(self.k)]
+
+    def decode_from(self, subset: Sequence[int], coded: jax.Array) -> jax.Array:
+        """Pick one received copy of each source row."""
+        return take_rows(coded, tuple(self.decode_positions(subset)))
 
     def encode_flops(self, row_elems: int) -> int:
         return 0  # pure copy

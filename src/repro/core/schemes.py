@@ -42,9 +42,12 @@ import functools
 import warnings
 from typing import Callable, Protocol, Sequence, runtime_checkable
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-from .coding import LTCode, MDSCode, ReplicationCode, device_matrix
+from .coding import (LTCode, MDSCode, ReplicationCode, device_matrix,
+                     take_rows)
 from .splitting import ConvSpec
 
 __all__ = [
@@ -54,6 +57,7 @@ __all__ = [
     "source_of_piece",
     "chunk_bounds",
     "decode_blocks",
+    "decode_pieces",
     "warm_decode_cache",
     "SimScenario",
     "SimPlan",
@@ -211,34 +215,94 @@ def chunk_bounds(width: int, chunks: int) -> list[tuple[int, int]]:
     return out
 
 
+def _column_blocks(shape: tuple[int, ...], chunks: int
+                   ) -> tuple[tuple[int, int], ...] | None:
+    """``chunk_bounds`` of the pieces' last axis, or None for one block."""
+    bounds = chunk_bounds(shape[-1], chunks) if shape else []
+    return tuple(bounds) if len(bounds) > 1 else None
+
+
+# The exit decode of a coded run as compiled programs with static shapes:
+# eagerly, stacking the arrived pieces is a program per piece plus a
+# concatenate, the flatten and the unflatten are programs of their own,
+# and a selection scheme's gather sends its indices to the device.  The
+# scheme's decode GEMM (the ``skinny_gemm`` kernel) stays its own program
+# between the stack and the unflatten.
+
+@functools.partial(jax.jit, static_argnames=("bounds", "keep"))
+def _piece_rows(pieces, bounds: tuple[tuple[int, int], ...] | None,
+                keep: tuple[int, ...] | None) -> tuple[jax.Array, ...]:
+    """Stack and flatten the m pieces (a tuple, or their stack) in one
+    program: the (m, F_b) row matrix of each column block of ``bounds``
+    (None: the whole piece).  ``keep`` lists the positions a selection
+    decode keeps, in source order (None: every piece)."""
+    if keep is not None:
+        pieces = [pieces[p] for p in keep]
+    stacked = jnp.stack(pieces) if isinstance(pieces, (tuple, list)) \
+        else pieces
+    m = stacked.shape[0]
+    if bounds is None:
+        return (stacked.reshape(m, -1),)
+    return tuple(stacked[..., a:b].reshape(m, -1) for a, b in bounds)
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "bounds"))
+def _source_blocks(blocks: tuple[jax.Array, ...], shape: tuple[int, ...],
+                   bounds: tuple[tuple[int, int], ...] | None) -> jax.Array:
+    """Unflatten and join in one program: the decoded (k, F_b) blocks ->
+    ``(k,) + shape``, side by side along the last axis."""
+    k = blocks[0].shape[0]
+    if bounds is None:
+        return blocks[0].reshape((k,) + shape)
+    return jnp.concatenate(
+        [blk.reshape((k,) + shape[:-1] + (b - a,))
+         for blk, (a, b) in zip(blocks, bounds)], axis=-1)
+
+
+def decode_pieces(scheme: CodingScheme, subset: Sequence[int], pieces,
+                  chunks: int = 1, decode=None):
+    """Decode the m arrived pieces of ``subset`` (a sequence in arrival
+    order, or their ``(m,) + piece_shape`` stack) into the sources
+    ``(k,) + piece_shape`` — optionally per column block along the last
+    axis (streamed gather, DESIGN.md §11).
+
+    One program stacks and flattens the pieces; the scheme's decode GEMM
+    runs on each block's rows as its own program (``decode``, by default
+    :func:`decode_blocks` on the (m, F_b) rows); one program unflattens
+    and joins the blocks.  A selection scheme (one with
+    ``decode_positions``) keeps its rows by static position inside the
+    stack program and runs no GEMM.  With the decode matrix on the device
+    (``coding.device_matrix``), a warmed decode transfers nothing.
+
+    Chunking only tiles the skinny decode GEMM over column blocks; the
+    decode matrix is shared, and each output element is still the same
+    length-m reduction over the same coded values, so the result is
+    identical to the one-shot decode.
+    """
+    subset = [int(i) for i in subset]
+    stacked = hasattr(pieces, "shape")
+    shape = tuple(pieces.shape[1:] if stacked else np.shape(pieces[0]))
+    bounds = _column_blocks(shape, chunks)
+    positions = getattr(scheme, "decode_positions", None)
+    keep = None if positions is None else tuple(positions(subset))
+    rows = _piece_rows(pieces if stacked else tuple(pieces), bounds, keep)
+    if keep is None:
+        decode = decode_blocks if decode is None else decode
+        rows = tuple(decode(scheme, subset, r) for r in rows)
+    return _source_blocks(rows, shape, bounds)
+
+
 def decode_blocks(scheme: CodingScheme, subset: Sequence[int], stacked,
                   chunks: int = 1):
     """Decode stacked coded pieces ``(m,) + piece_shape`` into sources
-    ``(k,) + piece_shape`` — optionally incrementally, per column block
-    along the last axis (streamed gather, DESIGN.md §11).
-
-    Chunking only tiles the skinny decode GEMM over column blocks; the
-    decode matrix itself (Vandermonde inverse / LT pseudo-inverse) is
-    solved once and shared via the scheme's lru caches, and each output
-    element is still the same length-m reduction over the same coded
-    values, so the result is identical to the one-shot decode.
-    """
-    import jax.numpy as jnp
-
-    subset = [int(i) for i in subset]
-    m = stacked.shape[0]
-    piece_shape = stacked.shape[1:]
-    width = int(piece_shape[-1]) if piece_shape else 1
-    c = max(1, min(int(chunks), width))
-    if c <= 1 or not piece_shape:
-        decoded = scheme.decode_from(subset, stacked.reshape(m, -1))
-        return decoded.reshape((scheme.k,) + piece_shape)
-    parts = []
-    for a, b in chunk_bounds(width, c):
-        blk = stacked[..., a:b]
-        dec = scheme.decode_from(subset, blk.reshape(m, -1))
-        parts.append(dec.reshape((scheme.k,) + blk.shape[1:]))
-    return jnp.concatenate(parts, axis=-1)
+    ``(k,) + piece_shape``, optionally per column block along the last
+    axis (:func:`decode_pieces`).  An (m, F) row matrix decoded in one
+    block is the scheme's ``decode_from`` alone: the GEMM, or a
+    selection's gather."""
+    if stacked.ndim == 2 and _column_blocks(stacked.shape[1:],
+                                            chunks) is None:
+        return scheme.decode_from([int(i) for i in subset], stacked)
+    return decode_pieces(scheme, subset, stacked, chunks)
 
 
 def warm_decode_cache(scheme: CodingScheme, limit: int = 64) -> int:
@@ -544,16 +608,20 @@ class UncodedScheme:
     def decodable(self, subset: Sequence[int]) -> bool:
         return {int(i) for i in subset} == set(range(self.n))
 
-    def decode_from(self, subset: Sequence[int], coded):
+    def decode_positions(self, subset: Sequence[int]) -> list[int]:
+        """Positions in ``subset`` of the first received copy of each
+        source row (duplicates carry no information but must not break
+        the decodable() => decodes contract)."""
         subset = [int(i) for i in subset]
         if not self.decodable(subset):
             raise ValueError("uncoded needs every worker's output")
-        # first received copy of each source row (duplicates carry no
-        # information but must not break the decodable() => decodes contract)
         pos: dict[int, int] = {}
         for p, i in enumerate(subset):
             pos.setdefault(i, p)
-        return coded[np.asarray([pos[s] for s in range(self.n)])]
+        return [pos[s] for s in range(self.n)]
+
+    def decode_from(self, subset: Sequence[int], coded):
+        return take_rows(coded, tuple(self.decode_positions(subset)))
 
     def encode_flops(self, row_elems: int) -> int:
         return 0
@@ -726,15 +794,15 @@ class LTScheme:
         """Least-squares decode over the received rows (m >= k allowed) —
         applied as a cached pseudo-inverse through the same skinny-GEMM
         kernel as MDS, so the per-subset solve is paid once (warmable at
-        startup) instead of per call as the seed's ``lstsq`` was."""
-        import jax.numpy as jnp
-
+        startup) instead of per call as the seed's ``lstsq`` was.  Its
+        device copy is kept per ordered subset and dtype, as MDS's is."""
         from ..kernels.ops import mds_decode
 
         sub = tuple(int(i) for i in subset)
-        D = _lt_decode_matrix(self.n, self.k, self.seed, self.c, self.delta,
-                              sub)
-        return mds_decode(jnp.asarray(D, dtype=coded.dtype), coded)
+        D = device_matrix(_lt_decode_matrix, (self.n, self.k, self.seed,
+                                              self.c, self.delta, sub),
+                          coded.dtype)
+        return mds_decode(D, coded)
 
     def encode_flops(self, row_elems: int) -> int:
         return int(2 * self.rows.sum() * row_elems)  # XOR-sums of d sources

@@ -27,7 +27,7 @@ from typing import Any, Callable, Sequence
 
 import jax.numpy as jnp
 
-from ..core.schemes import CodingScheme, decode_blocks
+from ..core.schemes import CodingScheme, decode_blocks, decode_pieces
 from ..telemetry.trace import span
 from .clock import Clock
 from .faults import ChurnSchedule, DelayModel, FaultPlan
@@ -119,10 +119,11 @@ class ExecHandle:
         scheme = self._scheme
         with span("model.decode", n=scheme.n, k=scheme.k,
                   pieces=len(subset)):
-            stacked = jnp.stack([jnp.asarray(self._results[i])
-                                 for i in subset])
-            self._out = decode_blocks(scheme, subset, stacked,
-                                      chunks=self._decode_chunks)
+            # compiled stack, decode GEMM, unflatten; the GEMM step goes
+            # through this module's ``decode_blocks`` on the (m, F) rows
+            self._out = decode_pieces(
+                scheme, subset, [self._results[i] for i in subset],
+                chunks=self._decode_chunks, decode=decode_blocks)
         return self._out
 
 

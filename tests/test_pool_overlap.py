@@ -282,11 +282,14 @@ class TestOverlappedServing:
 
 class TestWarmDecodeCache:
     def test_engine_startup_warms_every_k_subset(self):
-        from repro.core.coding import decode_matrix_cached
+        from repro.core.coding import decode_matrix_cached, device_matrix
 
         ex = CodedExecutor(N, clock=FakeClock(),
                            delay_model=DeterministicDelay(0.01))
         eng = Engine(_cfg(), seed=0, executor=ex)
+        # a cold process: no decode matrix has its device copy yet, so
+        # each subset's first decode reads the host solve
+        device_matrix.cache_clear()
         info0 = decode_matrix_cached.cache_info()
         sched = ServingScheduler(eng, max_seq=MAX_SEQ, max_batch=4,
                                  master_call_s=0.001)

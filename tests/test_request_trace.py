@@ -150,9 +150,11 @@ def test_one_encode_run_and_decode_per_segment(traced):
 def test_counters_agree_with_boundary_op_counter(traced):
     log, seg, ops = traced["log"], traced["segments"], traced["ops"]
     assert log.count("encodes") == log.count("decodes") == seg
-    assert log.count("encodes_compiled") == seg
+    assert log.count("encodes_compiled") == log.count("decodes_compiled") \
+        == seg
     assert ops == {"encode": seg, "decode": seg}
-    assert set(log.counters) == {"encodes", "decodes", "encodes_compiled"}
+    assert set(log.counters) == {"encodes", "decodes", "encodes_compiled",
+                                 "decodes_compiled"}
     # one dispatch of n pieces per run; every piece that started waited
     # in an inbox and computed
     assert log.n("backend.queue") == log.n("backend.compute") > 0
