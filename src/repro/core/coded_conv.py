@@ -10,23 +10,20 @@ decode recovers the *exact* uncoded output (up to f32 roundoff of the
 decode solve) — inference quality is unchanged (§II-B.4).
 
 The pipeline is written against the :class:`~repro.core.schemes.CodingScheme`
-protocol: any registered scheme (MDS, replication, LT, uncoded) slots in —
-``encode``/``decode_from`` are the only scheme-specific steps.  MDS and LT
-route their encode/decode GEMMs through the Pallas kernels
+protocol: any registered scheme (MDS, replication, LT, uncoded) slots in.
+The coded layout — encode the k partitions into n pieces, decode the
+arrived pieces back — is ``schemes.encode_pieces``/``decode_pieces``; MDS
+and LT route their encode/decode GEMMs through the Pallas kernels
 (kernels/mds_encode.py, kernels/mds_decode.py).
 
-Three execution modes:
-
-* ``coded_conv2d``            — single-host functional form (vmap over the n
-                                subtasks); used by tests / the simulator.
-                                Passing ``executor=`` (a
-                                ``repro.dist.CodedExecutor``) instead runs the
-                                n subtasks on the threaded worker pool and
-                                decodes at the k-th *arrival* — stragglers are
-                                cancelled, failures re-dispatched (DESIGN.md §7).
-* ``coded_conv2d_sharded``    — shard_map over a mesh "worker" axis: each
-                                device holds one coded partition; this is the
-                                TPU-pod adaptation (DESIGN.md §3).
+* ``run_segment``   — a coded segment: encode once, a chain of convs per
+                      piece, decode once (core/netplan.py's execution
+                      form); functional, or on a ``repro.dist.CodedExecutor``
+                      that decodes at the k-th *arrival* — stragglers are
+                      cancelled, failures re-dispatched (DESIGN.md §7).
+* ``coded_conv2d``  — one coded conv: a depth-1 ``run_segment``, or with
+                      ``executor=`` the backend's ``run_op`` (the threaded
+                      pool, or the mesh's one SPMD program, DESIGN.md §13).
 """
 from __future__ import annotations
 
@@ -37,20 +34,18 @@ from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from ..telemetry.trace import count, counting, span
 from .schemes import (CodingScheme, chunk_bounds, commutes_elementwise,
-                      decode_pieces, resolve_subset, source_of_piece)
-from .splitting import (ChainPlan, ConvSpec, SegmentSplitPlan, SplitPlan,
+                      decode_pieces, encode_pieces, resolve_subset,
+                      source_of_piece)
+from .splitting import (ChainPlan, ConvSpec, SegmentSplitPlan,
                         plan_segment_split, plan_width_split)
 
 __all__ = [
     "conv2d",
     "conv2d_chunked",
-    "split_input",
     "coded_conv2d",
-    "coded_conv2d_sharded",
     "run_segment",
     "boundary_op_counter",
     "ACTIVATIONS",
@@ -135,57 +130,11 @@ def conv2d_chunked(x: jax.Array, w: jax.Array, stride: int = 1,
     return jnp.concatenate(outs, axis=-1)
 
 
-def split_input(x: jax.Array, plan: SplitPlan) -> jax.Array:
-    """Stack the k overlapping input partitions: (B,C,H,W_I) -> (k,B,C,H,W_I^p)."""
-    return jnp.stack([x[..., p.a_i : p.b_i] for p in plan.parts])
-
-
-def _encode_partitions(code: CodingScheme, parts: jax.Array) -> jax.Array:
-    """(k, B,C,H,Wp) -> (n, B,C,H,Wp) via flatten -> encode -> unflatten (eq. 3)."""
-    k = parts.shape[0]
-    flat = parts.reshape(k, -1)
-    coded = code.encode(flat)
-    return coded.reshape((code.n,) + parts.shape[1:])
-
-
-# The entry encode of a coded segment (run_segment) as compiled programs
-# with static slice bounds: eagerly, each static slice ``x[..., a:b]``
-# becomes a dynamic_slice whose start indices cross to the device, and the
-# stack, flatten, unflatten and per-piece selections are programs of
-# their own.  The encode GEMM stays the scheme's own program between them.
-
-@functools.partial(jax.jit, static_argnames="bounds")
-def _column_slices(x: jax.Array, bounds: tuple[tuple[int, int], ...]
-                   ) -> tuple[jax.Array, ...]:
-    """``x[..., a:b]`` for each ``(a, b)`` of ``bounds``, in one program."""
-    return tuple(x[..., a:b] for a, b in bounds)
-
-
-@functools.partial(jax.jit, static_argnames="bounds")
-def _source_rows(x: jax.Array, bounds: tuple[tuple[int, int], ...]
-                 ) -> jax.Array:
-    """The k equal-width column slices of ``bounds``, stacked and
-    flattened: the (k, F) source matrix of the encode (eq. 3)."""
-    return jnp.stack(_column_slices(x, bounds)).reshape(len(bounds), -1)
-
-
-@functools.partial(jax.jit, static_argnames="shape")
-def _coded_pieces(coded: jax.Array, shape: tuple[int, ...]
-                  ) -> tuple[jax.Array, ...]:
-    """(n, F) coded rows -> the n pieces, each of ``shape``."""
-    pieces = coded.reshape((coded.shape[0],) + shape)
-    return tuple(pieces[i] for i in range(pieces.shape[0]))
-
-
 @jax.jit
 def _join_parts(parts: jax.Array) -> jax.Array:
     """The k decoded parts ``(k, B, C, H, W_p)`` side by side along W:
     ``(B, C, H, k * W_p)`` in one program (the segment exit's join)."""
     return jnp.concatenate(list(parts), axis=-1)
-
-
-def _bounds(chains: Sequence[ChainPlan]) -> tuple[tuple[int, int], ...]:
-    return tuple((cp.entry.a_i, cp.entry.b_i) for cp in chains)
 
 
 def _piece_chains(scheme: CodingScheme, split: SegmentSplitPlan
@@ -201,15 +150,9 @@ def _piece_chains(scheme: CodingScheme, split: SegmentSplitPlan
 
 def _entry_pieces(x: jax.Array, scheme: CodingScheme,
                   split: SegmentSplitPlan) -> tuple[jax.Array, ...]:
-    """The n pieces' entry inputs: a selection scheme's are its source
-    partitions' column slices (one program); a linear mix's are its encode
-    of the k partitions (the stacked slices, the scheme's encode GEMM, the
-    split into pieces).  No slice index crosses to the device."""
-    if commutes_elementwise(scheme):
-        return _column_slices(x, _bounds(_piece_chains(scheme, split)))
-    p0 = split.parts[0].entry
-    coded = scheme.encode(_source_rows(x, _bounds(split.parts)))
-    return _coded_pieces(coded, x.shape[:-1] + (p0.b_i - p0.a_i,))
+    """The n pieces' entry inputs: the encode of the k partitions' entry
+    windows along W (``schemes.encode_pieces``)."""
+    return encode_pieces(scheme, x, split.windows, -1)
 
 
 def coded_conv2d(
@@ -218,7 +161,6 @@ def coded_conv2d(
     code: CodingScheme,
     spec: ConvSpec,
     subset: Sequence[int] | None = None,
-    plan: SplitPlan | None = None,
     executor=None,
     assignment: Sequence[int] | None = None,
 ) -> jax.Array:
@@ -229,53 +171,23 @@ def coded_conv2d(
     outputs decoding consumes — the others are stragglers whose results are
     discarded, which we emulate by simply not consuming them.  It may hold
     more than k indices for schemes that need extra symbols (LT); ``None``
-    means the scheme's canonical decodable subset.
+    means the scheme's canonical decodable subset.  Without an executor
+    this is a depth-1 :func:`run_segment`.
 
-    With ``executor`` (a ``repro.dist.CodedExecutor``) the subset is not
-    chosen up front: the n subtasks run on the worker pool and the decode
-    consumes the first decodable *arrivals* (``executor.last_report`` has
-    the evidence).  ``assignment`` optionally gives per-worker piece counts
+    With ``executor`` (an ``ExecBackend``, dist/backend.py) the subset is
+    not chosen up front: the backend runs the op (``run_op``) and decodes
+    from the first decodable *arrivals* (``executor.last_report`` has the
+    evidence).  ``assignment`` optionally gives per-worker piece counts
     (``hetero.allocate_pieces``); ``subset`` is ignored in this mode.
     """
-    if plan is None:
-        plan = plan_width_split(spec, code.k)
-    # backend seam (dist/backend.py): the backend owns encode -> per-piece
-    # conv -> decode (the mesh backend fuses them into one shard_map
-    # program; the thread pool encodes on the master and thunks the
-    # pieces; run_segment's entry encode is the compiled one)
-    seam = executor is not None and hasattr(executor, "run_op")
-    p0 = plan.parts[0]
-    with _encode_span(code, code.n * math.prod(x.shape[:-1])
-                      * (p0.b_i - p0.a_i) * x.dtype.itemsize):
-        parts = split_input(x, plan)  # (k, B, C, H, W_I^p)
-        if not seam:
-            coded_in = _encode_partitions(code, parts)  # (n, ...)
-    if seam:
-        from ..dist.backend import CodedOp
+    if executor is None:
+        return run_segment(x, [w], code, [spec], [0], [None], subset=subset)
+    from ..dist.backend import CodedOp
 
-        y_parts = executor.run_op(
-            CodedOp("conv2d", code, parts, w, spec=spec,
-                    assignment=assignment))
-    elif executor is not None:
-        # legacy thunk surface: pre-seam executors and test doubles
-        y_parts = executor.run(
-            code,
-            [lambda i=i: conv2d(coded_in[i], w, spec.stride)
-             for i in range(code.n)],
-            assignment=assignment,
-        )  # (k, B, C_O, H_O, W_O^p)
-    else:
-        subset = resolve_subset(code, subset)
-        # Execution phase: each worker i computes f(X~_i), same weights.
-        with span("model.local"):
-            coded_out = jax.vmap(lambda xi: conv2d(xi, w, spec.stride))(
-                coded_in)
-        # Decoding phase: any sufficient subset of outputs decodes (eq. 4).
-        with span("model.decode", n=code.n, k=code.k, pieces=len(subset)):
-            sel = coded_out[jnp.asarray(subset)]
-            flat = sel.reshape(len(subset), -1)
-            decoded = code.decode_from(subset, flat)
-            y_parts = decoded.reshape((code.k,) + coded_out.shape[1:])
+    plan = plan_width_split(spec, code.k)
+    count("encodes")
+    y_parts = executor.run_op(
+        CodedOp("conv2d", code, x, w, spec=spec, assignment=assignment))
     count("decodes")
 
     # Reassemble on the width dim; master-kept remainder (footnote 2).
@@ -351,24 +263,24 @@ def run_segment(
     schemes (``schemes.commutes_elementwise``), so a linear-mix scheme
     with an interior activation or re-pad is rejected loudly rather than
     silently producing wrong output.  The final activation is NOT applied
-    here: the master applies it after decode (with any pooling), keeping
-    depth-1 segments numerically identical to ``coded_conv2d``.
+    here: the master applies it after decode (with any pooling), so a
+    depth-1 segment is ``coded_conv2d``'s functional form.
     ``biases[j]`` (None: no bias) is layer j's per-channel bias, affine
     as an activation is elementwise: the code mixes only the linear conv,
     interior biases run in the chains under selection schemes only, and
     the last layer's is the master's, after decode.
 
     The entry encode runs as compiled programs with static slice bounds
-    (``_entry_pieces``) and counts ``encodes_compiled``.  The exit decode
-    does too: the stack and flatten of the arrived pieces, the scheme's
-    decode GEMM, the unflatten (``schemes.decode_pieces``, in the
-    executor's ``result`` or here) and the join along W (``_join_parts``);
-    it counts ``decodes_compiled``.
+    (``_entry_pieces``: ``schemes.encode_pieces``) and counts
+    ``encodes_compiled``.  The exit decode does too: the stack and flatten
+    of the arrived pieces, the scheme's decode GEMM, the unflatten
+    (``schemes.decode_pieces``, in the executor's ``result`` or here) and
+    the join along W (``_join_parts``); it counts ``decodes_compiled``.
 
-    Functional form computes all n chains; with ``executor`` (a
-    ``repro.dist.CodedExecutor``) each chain is one multi-layer piece on
-    the worker pool, decoded at the k-th *arrival* with straggler
-    cancellation at segment granularity.
+    Functional form computes the decoded subset's chains; with
+    ``executor`` (a ``repro.dist.CodedExecutor``) each chain is one
+    multi-layer piece on the worker pool, decoded at the k-th *arrival*
+    with straggler cancellation at segment granularity.
     """
     d = len(specs)
     if biases is None:
@@ -445,55 +357,3 @@ def run_segment(
             y = jnp.concatenate([y, y_rem], axis=-1)
     return y
 
-
-def coded_conv2d_sharded(
-    x: jax.Array,
-    w: jax.Array,
-    code: CodingScheme,
-    spec: ConvSpec,
-    mesh: jax.sharding.Mesh,
-    axis: str = "model",
-) -> jax.Array:
-    """TPU-pod form: the n coded subtasks live on the ``axis`` mesh axis.
-
-    The master-side encode/decode become GEMMs against the generator /
-    decode matrices (Pallas kernels for MDS/LT); XLA partitions the
-    per-worker conv with zero cross-worker communication (each device's
-    partition is self-contained thanks to the halo split).  On real
-    hardware the fastest-subset selection is done by the serving runtime
-    (core/runtime.py); inside one SPMD program all n results are produced,
-    so we decode with the scheme's canonical subset — numerically identical
-    output, and the compiled artifact exercises the same collectives
-    (gather over the worker axis) as a fastest-k gather.
-    """
-    n = mesh.shape[axis]
-    if n != code.n:
-        raise ValueError(f"mesh axis {axis} has size {n}, code.n={code.n}")
-    plan = plan_width_split(spec, code.k)
-    parts = split_input(x, plan)  # (k, ...)
-    coded_in = _encode_partitions(code, parts)  # (n, ...)
-
-    @jax.jit
-    def _run(coded_in, w):
-        def worker(xi, w):
-            # xi: (1, B, C, H, W_I^p) — this device's coded partition.
-            return conv2d(xi[0], w, spec.stride)[None]
-
-        out = jax.shard_map(
-            worker,
-            mesh=mesh,
-            in_specs=(P(axis), P()),
-            out_specs=P(axis),
-        )(coded_in, w)
-        return out
-
-    coded_out = _run(coded_in, w)
-    subset = code.default_subset()
-    flat = coded_out[jnp.asarray(subset)].reshape(len(subset), -1)
-    decoded = code.decode_from(subset, flat)
-    y_parts = decoded.reshape((code.k,) + coded_out.shape[1:])
-    y = jnp.concatenate(list(y_parts), axis=-1)
-    if plan.remainder is not None:
-        r = plan.remainder
-        y = jnp.concatenate([y, conv2d(x[..., r.a_i : r.b_i], w, spec.stride)], axis=-1)
-    return y

@@ -18,23 +18,13 @@ from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from ..telemetry.trace import count
-from .schemes import (CodingScheme, commutes_elementwise, resolve_subset,
-                      source_of_piece)
-from .splitting import SplitPlan, plan_token_split
+from .schemes import (CodingScheme, commutes_elementwise, decode_pieces,
+                      encode_pieces, resolve_subset)
+from .splitting import plan_token_split
 
-__all__ = ["coded_matmul", "coded_matmul_sharded", "coded_ffn_segment"]
-
-
-def _encode_tokens(code: CodingScheme, x: jax.Array, plan: SplitPlan) -> jax.Array:
-    """(T, d) tokens -> (n, T_p, d) coded token slices."""
-    k = code.k
-    t_p = plan.w_out_p
-    parts = x[: k * t_p].reshape(k, t_p, -1)
-    flat = parts.reshape(k, -1)
-    return code.encode(flat).reshape(code.n, t_p, x.shape[-1])
+__all__ = ["coded_matmul", "coded_ffn_segment"]
 
 
 def coded_matmul(
@@ -48,49 +38,30 @@ def coded_matmul(
     """Exact Y = X @ W recovered from a decodable subset of the n coded
     worker GEMMs, under any registered scheme.
 
-    x: (T, d_in), w: (d_in, d_out).  The remainder rows (T mod k) are
-    computed by the master (paper footnote 2).
+    x: (T, d_in), w: (d_in, d_out).  The k token blocks are encoded into
+    the n pieces and decoded back by ``schemes.encode_pieces`` /
+    ``decode_pieces``; the remainder rows (T mod k) are computed by the
+    master (paper footnote 2).
 
-    With ``executor`` (a ``repro.dist.CodedExecutor``) the n GEMM subtasks
-    run on the worker pool and the decode consumes the first decodable
+    With ``executor`` (an ``ExecBackend``, dist/backend.py) the backend
+    runs the whole op (``run_op``) and decodes from the first decodable
     arrivals; ``subset`` is ignored, ``assignment`` optionally routes
     per-worker piece counts (``hetero.allocate_pieces``).
     """
-    T = x.shape[0]
-    plan = plan_token_split(T, code.k)
-    if executor is not None and hasattr(executor, "run_op"):
-        # backend seam (dist/backend.py): hand the backend the whole op —
-        # source stack + weights — so encode/shard-GEMM/decode can run
-        # where the backend wants them (the thread pool encodes eagerly;
-        # the mesh fuses all three into one shard_map program)
+    plan = plan_token_split(x.shape[0], code.k)
+    if executor is not None:
         from ..dist.backend import CodedOp
 
-        parts = x[: code.k * plan.w_out_p].reshape(code.k, plan.w_out_p, -1)
         count("encodes")
         decoded = executor.run_op(
-            CodedOp("matmul", code, parts, w, assignment=assignment))
-        y = decoded.reshape(code.k * plan.w_out_p, w.shape[-1])
-        count("decodes")
-        if plan.remainder is not None:
-            y = jnp.concatenate([y, x[plan.remainder.a_i :] @ w], axis=0)
-        return y
-    coded_in = _encode_tokens(code, x, plan)  # (n, T_p, d_in)
-    count("encodes")
-    if executor is not None:
-        # legacy thunk surface: pre-seam executors and test doubles
-        decoded = executor.run(
-            code,
-            [lambda i=i: coded_in[i] @ w for i in range(code.n)],
-            assignment=assignment,
-        )  # (k, T_p, d_out)
-        y = decoded.reshape(code.k * plan.w_out_p, w.shape[-1])
+            CodedOp("matmul", code, x, w, assignment=assignment))
     else:
         subset = resolve_subset(code, subset)
-        coded_out = jnp.einsum("ntd,df->ntf", coded_in, w)  # n worker GEMMs
-        sel = coded_out[jnp.asarray(subset)]
-        decoded = code.decode_from(subset, sel.reshape(len(subset), -1))
-        y = decoded.reshape(code.k * plan.w_out_p, w.shape[-1])
+        pieces = encode_pieces(code, x, plan.windows, 0)
+        count("encodes")
+        decoded = decode_pieces(code, subset, [pieces[i] @ w for i in subset])
     count("decodes")
+    y = decoded.reshape(code.k * plan.w_out_p, w.shape[-1])
     if plan.remainder is not None:
         y = jnp.concatenate([y, x[plan.remainder.a_i :] @ w], axis=0)
     return y
@@ -127,63 +98,25 @@ def coded_ffn_segment(
             f"scheme {getattr(code, 'scheme_name', code)} is a linear mix: "
             "the FFN activation cannot run inside a coded token slice — "
             "use per-GEMM coded_matmul (decode before each activation)")
-    T = x.shape[0]
-    plan = plan_token_split(T, code.k)
+    plan = plan_token_split(x.shape[0], code.k)
 
     def chain(xt: jax.Array) -> jax.Array:
         h = xt @ w_in
         h = act(xt @ w_gate) * h if w_gate is not None else act(h)
         return h @ w_out
 
-    t_p = plan.w_out_p
-    srcs = [source_of_piece(code, i) for i in range(code.n)]
-    piece_in = [x[s * t_p:(s + 1) * t_p] for s in srcs]
+    pieces = encode_pieces(code, x, plan.windows, 0)
     count("encodes")  # the selection dispatch is the boundary op
     if executor is not None:
         decoded = executor.run(
-            code, [lambda i=i: chain(piece_in[i]) for i in range(code.n)],
+            code, [lambda p=p: chain(p) for p in pieces],
             assignment=assignment)
-        y = decoded.reshape(code.k * t_p, w_out.shape[-1])
     else:
         subset = resolve_subset(code, subset)
-        outs = jnp.stack([chain(piece_in[i]) for i in subset])
-        decoded = code.decode_from(subset, outs.reshape(len(subset), -1))
-        y = decoded.reshape(code.k * t_p, w_out.shape[-1])
+        decoded = decode_pieces(code, subset, [chain(pieces[i])
+                                               for i in subset])
     count("decodes")
+    y = decoded.reshape(code.k * plan.w_out_p, w_out.shape[-1])
     if plan.remainder is not None:
         y = jnp.concatenate([y, chain(x[plan.remainder.a_i:])], axis=0)
-    return y
-
-
-def coded_matmul_sharded(
-    x: jax.Array,
-    w: jax.Array,
-    code: CodingScheme,
-    mesh: jax.sharding.Mesh,
-    axis: str = "model",
-) -> jax.Array:
-    """Pod form: n coded GEMM subtasks on the ``axis`` mesh axis."""
-    n = mesh.shape[axis]
-    if n != code.n:
-        raise ValueError(f"mesh axis {axis} has size {n}, code.n={code.n}")
-    T = x.shape[0]
-    plan = plan_token_split(T, code.k)
-    coded_in = _encode_tokens(code, x, plan)
-
-    @jax.jit
-    def _run(coded_in, w):
-        def worker(xi, w):
-            return jnp.einsum("ntd,df->ntf", xi, w)
-
-        return jax.shard_map(
-            worker, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(axis)
-        )(coded_in, w)
-
-    coded_out = _run(coded_in, w)
-    subset = code.default_subset()
-    decoded = code.decode_from(
-        subset, coded_out[jnp.asarray(subset)].reshape(len(subset), -1))
-    y = decoded.reshape(code.k * plan.w_out_p, w.shape[-1])
-    if plan.remainder is not None:
-        y = jnp.concatenate([y, x[plan.remainder.a_i :] @ w], axis=0)
     return y

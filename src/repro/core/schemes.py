@@ -18,7 +18,9 @@ and registers itself under a name (``get_scheme("mds"|"replication"|"lt"|
 (coded_conv.py / coded_linear.py / serving/engine.py) and the simulator
 (runtime.py) are written against the protocol only, so "uncoded" stops
 being a special case and new schemes (e.g. sparsity-aware codes, arXiv
-2411.01579) drop in without touching either layer.
+2411.01579) drop in without touching either layer.  The coded layout —
+k sources to (k, F) rows to n pieces, and back from the arrived pieces —
+is written once, as :func:`encode_pieces` and :func:`decode_pieces`.
 
 Simulation hooks
 ----------------
@@ -58,6 +60,7 @@ __all__ = [
     "chunk_bounds",
     "decode_blocks",
     "decode_pieces",
+    "encode_pieces",
     "warm_decode_cache",
     "SimScenario",
     "SimPlan",
@@ -220,6 +223,63 @@ def _column_blocks(shape: tuple[int, ...], chunks: int
     """``chunk_bounds`` of the pieces' last axis, or None for one block."""
     bounds = chunk_bounds(shape[-1], chunks) if shape else []
     return tuple(bounds) if len(bounds) > 1 else None
+
+
+# The entry encode of a coded op as compiled programs with static windows:
+# eagerly, each static slice becomes a dynamic_slice whose start indices
+# cross to the device, and the stack, flatten, unflatten and per-piece
+# selections are programs of their own.  The encode GEMM stays the
+# scheme's own program between them.
+
+Windows = tuple[tuple[int, int], ...]
+
+
+@functools.partial(jax.jit, static_argnames=("bounds", "axis"))
+def _column_slices(x: jax.Array, bounds: Windows, axis: int
+                   ) -> tuple[jax.Array, ...]:
+    """``x``'s range ``[a, b)`` along ``axis`` for each ``(a, b)`` of
+    ``bounds``, in one program."""
+    lead = (slice(None),) * (axis % x.ndim)
+    return tuple(x[lead + (slice(a, b),)] for a, b in bounds)
+
+
+@functools.partial(jax.jit, static_argnames=("bounds", "axis"))
+def _source_rows(x: jax.Array, bounds: Windows, axis: int) -> jax.Array:
+    """The k equal-width slices of ``bounds``, stacked and flattened: the
+    (k, F) source matrix of the encode (eq. 3)."""
+    return jnp.stack(_column_slices(x, bounds, axis)).reshape(len(bounds), -1)
+
+
+@functools.partial(jax.jit, static_argnames="shape")
+def _coded_pieces(coded: jax.Array, shape: tuple[int, ...]
+                  ) -> tuple[jax.Array, ...]:
+    """(n, F) coded rows -> the n pieces, each of ``shape``."""
+    pieces = coded.reshape((coded.shape[0],) + shape)
+    return tuple(pieces[i] for i in range(pieces.shape[0]))
+
+
+def encode_pieces(scheme: CodingScheme, x: jax.Array, windows: Windows,
+                  axis: int) -> tuple[jax.Array, ...]:
+    """Encode the k sources, ``x``'s static ``windows`` along ``axis``
+    (a conv's width partitions on the last axis, a GEMM's token blocks on
+    the first), into the n coded pieces (eq. 3).
+
+    A linear mix (MDS, LT) runs three programs: the slices, stack and
+    flatten to the (k, F) source rows; the scheme's encode GEMM; the split
+    into pieces.  A selection scheme (replication, uncoded) takes each
+    piece's source window by static position (:func:`source_of_piece`) in
+    one program and runs no GEMM, so its windows may differ in width.  No
+    slice bound crosses to the device; with the encode matrix on the
+    device (``coding.device_matrix``) a warmed encode transfers nothing.
+    """
+    if commutes_elementwise(scheme):
+        return _column_slices(
+            x, tuple(windows[source_of_piece(scheme, i)]
+                     for i in range(scheme.n)), axis)
+    coded = scheme.encode(_source_rows(x, windows, axis))
+    shape = list(x.shape)
+    shape[axis] = windows[0][1] - windows[0][0]
+    return _coded_pieces(coded, tuple(shape))
 
 
 # The exit decode of a coded run as compiled programs with static shapes:

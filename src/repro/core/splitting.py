@@ -130,6 +130,12 @@ class SplitPlan:
     def w_in_p(self) -> int:
         return self.parts[0].w_in
 
+    @property
+    def windows(self) -> Tuple[Tuple[int, int], ...]:
+        """The k partitions' input ranges ``(a_i, b_i)``: the sources'
+        static windows (``schemes.encode_pieces``)."""
+        return tuple((p.a_i, p.b_i) for p in self.parts)
+
 
 def plan_width_split(spec: ConvSpec, k: int) -> SplitPlan:
     """Split ``spec``'s output into k equal width slices (eqs. 1-2)."""
@@ -241,6 +247,12 @@ class SegmentSplitPlan:
     @property
     def w_entry_max(self) -> int:
         return max(p.w_entry for p in self.parts)
+
+    @property
+    def windows(self) -> Tuple[Tuple[int, int], ...]:
+        """The k chains' entry ranges ``(a_i, b_i)`` in the segment's
+        entry input: the sources' static windows."""
+        return tuple((p.entry.a_i, p.entry.b_i) for p in self.parts)
 
 
 def validate_chain_geometry(specs: Sequence[ConvSpec],

@@ -9,7 +9,7 @@ k-of-n saving measurable.
 """
 from .adaptive import AdaptiveExecutor, AdaptivePlan, AdaptivePlanner, gemm_spec
 from .autoscale import Autoscaler, CostModel, ScaleDecision
-from .backend import CodedOp, ExecBackend, run_coded_op
+from .backend import CodedOp, ExecBackend
 from .clock import (
     Clock,
     FakeClock,
@@ -56,7 +56,6 @@ __all__ = [
     "stream_chunk_count",
     "CodedOp",
     "ExecBackend",
-    "run_coded_op",
     "CodedExecutor",
     "ExecHandle",
     "MeshExecutor",

@@ -25,26 +25,29 @@ from typing import Any, Mapping, Protocol, Sequence, runtime_checkable
 
 import jax
 
-from ..core.splitting import ConvSpec
+from ..core.splitting import ConvSpec, plan_token_split, plan_width_split
 
-__all__ = ["CodedOp", "ExecBackend", "run_coded_op"]
+__all__ = ["CodedOp", "ExecBackend"]
 
 
 @dataclass(frozen=True)
 class CodedOp:
     """One coded operator, backend-agnostically described.
 
-    ``kind`` selects the math:
-      * ``"matmul"``: ``x`` is the stacked per-source token blocks with
-        shape (k, t_p, d_in) and ``w`` is (d_in, d_out); piece i computes
-        ``encode(x)[i] @ w``.
-      * ``"conv2d"``: ``x`` is the stacked per-source width partitions
-        (k, N, C, H, W_p) (halos already included) and ``w`` is OIHW;
-        piece i computes ``conv2d(encode(x)[i], w, spec.stride)``.
+    ``x`` is the op's whole input; its k sources are the static
+    ``windows`` of one axis (``axis``), and the master keeps what lies
+    past them (the remainder, footnote 2).  ``kind`` selects the math:
+      * ``"matmul"``: ``x`` is (T, d_in) tokens, the sources are its k
+        token blocks (the first axis) and ``w`` is (d_in, d_out); piece i
+        computes ``pieces[i] @ w``.
+      * ``"conv2d"``: ``x`` is the padded (N, C, H, W) input, the sources
+        are its k width partitions, halos included (the last axis), and
+        ``w`` is OIHW; piece i computes ``conv2d(pieces[i], w,
+        spec.stride)``.
 
     The decoded result a backend must return is the (k,) + piece-shape
     stack of recovered source outputs — exactly what
-    ``core.schemes.decode_blocks`` yields from the first decodable subset.
+    ``core.schemes.decode_pieces`` yields from the first decodable subset.
     """
 
     kind: str
@@ -60,6 +63,18 @@ class CodedOp:
             raise ValueError(f"unknown CodedOp kind: {self.kind!r}")
         if self.kind == "conv2d" and self.spec is None:
             raise ValueError("conv2d CodedOp requires a ConvSpec")
+
+    @property
+    def axis(self) -> int:
+        """The axis the sources tile: tokens first, width last."""
+        return 0 if self.kind == "matmul" else -1
+
+    @property
+    def windows(self) -> tuple[tuple[int, int], ...]:
+        """The k sources' static ``[a, b)`` ranges along ``axis``."""
+        if self.kind == "matmul":
+            return plan_token_split(self.x.shape[0], self.scheme.k).windows
+        return plan_width_split(self.spec, self.scheme.k).windows
 
 
 @runtime_checkable
@@ -98,30 +113,3 @@ class ExecBackend(Protocol):
 
     def close(self) -> None: ...
 
-
-def run_coded_op(executor: Any, op: CodedOp) -> jax.Array:
-    """Dispatch ``op`` on ``executor`` via the backend seam.
-
-    Prefers ``run_op`` (the ``ExecBackend`` protocol); falls back to the
-    legacy thunk-list ``run(scheme, fns, ...)`` surface so hand-rolled
-    test doubles predating the seam keep working.
-    """
-    run_op = getattr(executor, "run_op", None)
-    if run_op is not None:
-        return run_op(op)
-    from ..core import coded_conv, coded_linear  # lazy: avoid import cycle
-
-    if op.kind == "matmul":
-        coded_in = op.scheme.encode(op.x.reshape(op.x.shape[0], -1)).reshape(
-            op.scheme.n, op.x.shape[1], op.x.shape[2]
-        )
-        fns = [lambda i=i: coded_in[i] @ op.w for i in range(op.scheme.n)]
-    else:
-        coded_in = coded_conv._encode_partitions(op.scheme, op.x)
-        fns = [
-            lambda i=i: coded_conv.conv2d(coded_in[i], op.w, op.spec.stride)
-            for i in range(op.scheme.n)
-        ]
-    return executor.run(
-        op.scheme, fns, assignment=op.assignment, decode_chunks=op.decode_chunks
-    )

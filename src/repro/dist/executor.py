@@ -27,7 +27,8 @@ from typing import Any, Callable, Sequence
 
 import jax.numpy as jnp
 
-from ..core.schemes import CodingScheme, decode_blocks, decode_pieces
+from ..core.schemes import (CodingScheme, decode_blocks, decode_pieces,
+                            encode_pieces)
 from ..telemetry.trace import span
 from .clock import Clock
 from .faults import ChurnSchedule, DelayModel, FaultPlan
@@ -212,32 +213,26 @@ class CodedExecutor:
 
     def run_op(self, op) -> jnp.ndarray:
         """``ExecBackend`` entry point (dist/backend.py): encode the op's
-        source stack eagerly, thunk one piece each, and delegate to
-        ``self.run`` — so ``AdaptiveExecutor``'s run override (probing,
-        auto-assignment, report observation) composes unchanged."""
-        from ..core.coded_conv import _encode_partitions, conv2d
+        sources (``schemes.encode_pieces``), thunk one piece each, and
+        delegate to ``self.run`` — so ``AdaptiveExecutor``'s run override
+        (probing, auto-assignment, report observation) composes unchanged."""
+        from ..core.coded_conv import conv2d
         from ..kernels.mds_encode import skinny_gemm_pallas
 
-        scheme = op.scheme
+        scheme, windows = op.scheme, op.windows
+        a, b = windows[0]
         with span("model.encode", n=scheme.n, k=scheme.k,
-                  bytes=scheme.n * op.x.nbytes // op.x.shape[0]):
-            if op.kind == "matmul":
-                k, t_p, d = op.x.shape
-                coded_in = scheme.encode(op.x.reshape(k, -1)).reshape(
-                    scheme.n, t_p, d)
-            else:
-                coded_in = _encode_partitions(scheme, op.x)
+                  bytes=scheme.n * (b - a) * op.x.nbytes
+                  // op.x.shape[op.axis]):
+            pieces = encode_pieces(scheme, op.x, windows, op.axis)
         if op.kind == "matmul":
             # the SAME worker kernel the mesh backend shards — a plain `@`
             # lets XLA pick a shape-dependent GEMM algorithm, which breaks
             # byte-for-byte equality across backends at some piece shapes
-            fns = [lambda i=i: skinny_gemm_pallas(coded_in[i], op.w)
-                   for i in range(scheme.n)]
+            fns = [lambda p=p: skinny_gemm_pallas(p, op.w) for p in pieces]
         else:
-            fns = [
-                lambda i=i: conv2d(coded_in[i], op.w, op.spec.stride)
-                for i in range(scheme.n)
-            ]
+            fns = [lambda p=p: conv2d(p, op.w, op.spec.stride)
+                   for p in pieces]
         return self.run(scheme, fns, assignment=op.assignment,
                         decode_chunks=op.decode_chunks)
 

@@ -13,7 +13,9 @@ into a single SPMD program per (op shape, scheme, fault pattern):
   piece with the Pallas skinny-GEMM kernel (kernels/mds_encode.py);
   selection schemes (replication/uncoded) carry a per-slice source index
   and gather instead, so copies are bit-exact (a 0/1 matrix encode would
-  rewrite ``-0.0`` to ``+0.0``).
+  rewrite ``-0.0`` to ``+0.0``).  This per-slice encode is the one coded
+  layout outside ``core.schemes.encode_pieces``: each slice computes one
+  generator row, not the master's whole (n, F) GEMM.
 * **shard compute** — the piece GEMM runs through the same Pallas kernel
   (``skinny_gemm_pallas``); the piece conv is the identical
   ``lax.conv`` the threaded backend's thunks call, so both backends
@@ -248,40 +250,31 @@ class MeshExecutor:
             Gp = jnp.asarray(Gp)
 
         if op.kind == "matmul":
-            t_p, d_in = op.x.shape[1], op.x.shape[2]
-
-            def worker(enc, m, x, w):
-                if selection:
-                    piece = jnp.take(x, enc[0], axis=0)
-                else:
-                    flat = x.reshape(k, t_p * d_in)
-                    piece = mds_encode(enc, flat,
-                                       interpret=interpret).reshape(t_p, d_in)
-                y = skinny_gemm_pallas(piece, w, interpret=interpret)
-                return (y * m[0].astype(y.dtype))[None]
+            compute = lambda piece, w: skinny_gemm_pallas(
+                piece, w, interpret=interpret)
         else:
             from ..core.coded_conv import conv2d
 
             stride = op.spec.stride
+            compute = lambda piece, w: conv2d(piece, w, stride)
 
-            def worker(enc, m, x, w):
-                if selection:
-                    piece = jnp.take(x, enc[0], axis=0)
-                else:
-                    flat = x.reshape(k, -1)
-                    piece = mds_encode(enc, flat, interpret=interpret
-                                       ).reshape(x.shape[1:])
-                y = conv2d(piece, w, stride)
-                return (y * m[0].astype(y.dtype))[None]
+        def worker(enc, m, x, w):
+            # x: the replicated (k,) + source-shape stack
+            if selection:
+                piece = jnp.take(x, enc[0], axis=0)
+            else:
+                piece = mds_encode(enc, x.reshape(k, -1), interpret=interpret
+                                   ).reshape(x.shape[1:])
+            y = compute(piece, w)
+            return (y * m[0].astype(y.dtype))[None]
 
         # piece-stacked output rank equals the source-stacked input rank:
         # (k, t_p, d_in) -> (ndev, t_p, d_out); (k,N,C,H,Wp) -> (ndev,N,O,H',Wp')
         enc_arg = src if selection else Gp
-        nd_out = op.x.ndim
         fan_out = jax.shard_map(
             worker, mesh=mesh,
             in_specs=(P(axis), P(axis), P(), P()),
-            out_specs=piece_spec(nd_out, axis), check_vma=False)
+            out_specs=piece_spec(op.x.ndim + 1, axis), check_vma=False)
         sub_idx = jnp.asarray(list(subset), jnp.int32)
         subset_l = list(subset)
 
@@ -294,8 +287,12 @@ class MeshExecutor:
                 mesh=mesh, in_specs=(spec,), out_specs=spec,
                 check_vma=False)(stacked)
 
+        windows, src_axis = op.windows, op.axis
+
         def program(x, w):
-            pieces = fan_out(enc_arg, mask, x, w)
+            sources = jnp.stack([jax.lax.slice_in_dim(x, a, b, axis=src_axis)
+                                 for a, b in windows])
+            pieces = fan_out(enc_arg, mask, sources, w)
             gathered = jnp.take(pieces, sub_idx, axis=0)
             if gathered.shape[-1] % ndev == 0:
                 return sharded_decode(gathered)
@@ -307,7 +304,7 @@ class MeshExecutor:
         stride = op.spec.stride if op.spec is not None else None
         return (op.kind, _scheme_key(op.scheme), tuple(op.x.shape),
                 str(op.x.dtype), tuple(op.w.shape), str(op.w.dtype),
-                stride, subset)
+                stride, op.windows, subset)
 
     def run_op(self, op) -> jax.Array:
         """Run one coded op end-to-end on the mesh; return the decoded

@@ -17,11 +17,11 @@ import pytest
 
 from repro.core.coded_conv import coded_conv2d, conv2d
 from repro.core.coded_linear import coded_matmul
-from repro.core.schemes import decode_blocks, get_scheme, scheme_names
+from repro.core.schemes import get_scheme, scheme_names
 from repro.core.splitting import ConvSpec
 from repro.dist import (CodedExecutor, DeterministicDelay, FakeClock,
                         FaultPlan, MeshExecutor)
-from repro.dist.backend import CodedOp, ExecBackend, run_coded_op
+from repro.dist.backend import CodedOp, ExecBackend
 from repro.launch.mesh import PiecePlacementError, make_local_mesh
 from repro.models.model import ModelConfig
 from repro.serving import Engine, Request, ServingScheduler
@@ -119,7 +119,7 @@ class TestCrossBackendDecodePaths:
 
 
 # ---------------------------------------------------------------------------
-# the seam itself: protocol conformance, CodedOp validation, legacy fallback
+# the seam itself: protocol conformance, CodedOp validation
 # ---------------------------------------------------------------------------
 
 class TestBackendSeam:
@@ -143,24 +143,6 @@ class TestBackendSeam:
         with pytest.raises(ValueError, match="ConvSpec"):
             CodedOp("conv2d", code, x, w)
 
-    def test_run_coded_op_falls_back_to_legacy_thunks(self, rng):
-        # a pre-seam double exposing only run(scheme, fns): run_coded_op
-        # must still drive it — encode eagerly, hand it piece thunks
-        code = _scheme("mds")
-        x = jnp.asarray(rng.normal(size=(code.k, 4, 8)), jnp.float32)
-        w = jnp.asarray(rng.normal(size=(8, 4)), jnp.float32)
-
-        class Legacy:
-            def run(self, scheme, fns, assignment=None, decode_chunks=1):
-                outs = jnp.stack([f() for f in fns])
-                sub = list(scheme.default_subset())
-                return decode_blocks(scheme, sub,
-                                     outs[jnp.asarray(sub)])
-
-        y = run_coded_op(Legacy(), CodedOp("matmul", code, x, w))
-        ref = jnp.einsum("ktd,df->ktf", x, w)
-        assert np.allclose(y, ref, rtol=1e-3, atol=2e-3)
-
 
 # ---------------------------------------------------------------------------
 # MeshExecutor specifics: compile-once, placement errors, report surface
@@ -170,8 +152,8 @@ class TestMeshExecutor:
     def test_compile_once_per_shape(self, rng):
         code = _scheme("mds")
         w = jnp.asarray(rng.normal(size=(8, 16)), jnp.float32)
-        xa = jnp.asarray(rng.normal(size=(code.k, 4, 8)), jnp.float32)
-        xb = jnp.asarray(rng.normal(size=(code.k, 6, 8)), jnp.float32)
+        xa = jnp.asarray(rng.normal(size=(code.k * 4, 8)), jnp.float32)
+        xb = jnp.asarray(rng.normal(size=(code.k * 6, 8)), jnp.float32)
         with MeshExecutor() as ex:
             ex.run_op(CodedOp("matmul", code, xa, w))
             ex.run_op(CodedOp("matmul", code, xa, w))
@@ -182,7 +164,7 @@ class TestMeshExecutor:
 
     def test_too_many_pieces_is_typed(self, rng):
         code = get_scheme("mds").make(9, 3)  # 9 pieces > 8 device slices
-        x = jnp.asarray(rng.normal(size=(3, 4, 8)), jnp.float32)
+        x = jnp.asarray(rng.normal(size=(3 * 4, 8)), jnp.float32)
         w = jnp.asarray(rng.normal(size=(8, 4)), jnp.float32)
         with MeshExecutor() as ex:
             with pytest.raises(PiecePlacementError, match="extent"):
@@ -203,7 +185,7 @@ class TestMeshExecutor:
         with pytest.raises(PiecePlacementError, match="no 'nope' axis"):
             MeshExecutor(axis="nope")
         code = _scheme("mds")
-        x = jnp.asarray(rng.normal(size=(3, 4, 8)), jnp.float32)
+        x = jnp.asarray(rng.normal(size=(3 * 4, 8)), jnp.float32)
         w = jnp.asarray(rng.normal(size=(8, 4)), jnp.float32)
         with MeshExecutor(order=(0, 0, 1, 2, 3)) as ex:
             with pytest.raises(ValueError, match="permutation"):
@@ -216,7 +198,7 @@ class TestMeshExecutor:
 
     def test_report_surface(self, rng):
         code = _scheme("mds")
-        x = jnp.asarray(rng.normal(size=(code.k, 4, 8)), jnp.float32)
+        x = jnp.asarray(rng.normal(size=(code.k * 4, 8)), jnp.float32)
         w = jnp.asarray(rng.normal(size=(8, 4)), jnp.float32)
         seen = []
         with MeshExecutor(dead=(1,)) as ex:

@@ -98,7 +98,7 @@ def test_mesh_program_compiles_on_four_chips(topo):
                 axis_types=(jax.sharding.AxisType.Auto,) * 2)
     replicated = NamedSharding(mesh, P())
     scheme = get_scheme("mds").make(4, 3)
-    x = _sds((3, 171, 2048), replicated)
+    x = _sds((3 * 171, 2048), replicated)
     w = _sds((2048, 16384), replicated)
     ex = MeshExecutor(mesh, dead=(2,), interpret=False)
     program = ex._build(CodedOp("matmul", scheme, x, w), ex._subset(scheme))

@@ -1,5 +1,6 @@
 """Coded execution == uncoded execution (the paper's §II-B.4 exactness
-claim), for conv and the GEMM adaptation, single-host and shard_map."""
+claim), for conv and the GEMM adaptation (the mesh backend's are in
+tests/test_backend_equiv.py)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,7 +13,9 @@ from repro.core import (
     coded_conv2d,
     coded_matmul,
     conv2d,
+    get_scheme,
     plan_width_split,
+    scheme_names,
 )
 
 
@@ -80,17 +83,42 @@ def test_straggler_insensitivity():
                                    rtol=5e-3, atol=5e-3)
 
 
-def test_sharded_matches_local():
-    """shard_map worker-axis execution == single-host functional form."""
-    from repro.core.coded_conv import coded_conv2d_sharded
+def _transfer_case(name):
+    cls = get_scheme(name)
+    return cls.make(5, 3) if name in ("mds", "lt") else cls.make(4)
 
-    n_dev = len(jax.devices())
-    mesh = jax.make_mesh((1, n_dev), ("data", "model"),
-                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    spec = ConvSpec(c_in=4, c_out=6, h_in=8, w_in=12, kernel=3, stride=1)
-    code = MDSCode(n_dev, max(n_dev - 1, 1))
-    x, w = _rand_conv(jax.random.PRNGKey(0), spec)
-    ref = conv2d(x, w, 1)
-    out = coded_conv2d_sharded(x, w, code, spec, mesh)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+
+@pytest.mark.parametrize("path", ["functional", "executor"])
+@pytest.mark.parametrize("op", ["conv2d", "matmul"])
+@pytest.mark.parametrize("name", scheme_names())
+def test_a_warmed_coded_op_transfers_nothing(name, op, path):
+    """A warmed ``coded_conv2d``/``coded_matmul`` call's master-side encode
+    and decode move nothing from the host: the windows are static and the
+    coding matrices stay on the device.  Widths and token counts divide by
+    k, so no remainder (master-local work, not coding) is sliced; worker
+    threads run outside the guard, which holds the calling thread."""
+    from repro.dist import CodedExecutor, DeterministicDelay, FakeClock
+
+    code = _transfer_case(name)
+    if op == "conv2d":
+        spec = ConvSpec(c_in=3, c_out=4, h_in=6, w_in=14, kernel=3)
+        x, w = _rand_conv(jax.random.PRNGKey(0), spec)
+        call = lambda ex: coded_conv2d(x, w, code, spec, executor=ex)
+        ref = conv2d(x, w, 1)
+    else:
+        x = jax.random.normal(jax.random.PRNGKey(0), (12, 8))
+        w = jax.random.normal(jax.random.PRNGKey(1), (8, 5))
+        call = lambda ex: coded_matmul(x, w, code, executor=ex)
+        ref = x @ w
+    ex = (CodedExecutor(code.n, clock=FakeClock(),
+                        delay_model=DeterministicDelay(1.0))
+          if path == "executor" else None)
+    try:
+        jax.block_until_ready(call(ex))
+        with jax.transfer_guard_host_to_device("disallow"):
+            y = jax.block_until_ready(call(ex))
+    finally:
+        if ex is not None:
+            ex.close()
+    np.testing.assert_allclose(np.asarray(y), np.asarray(ref),
                                rtol=5e-3, atol=5e-3)

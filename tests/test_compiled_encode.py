@@ -4,7 +4,7 @@ flatten in one program, the scheme's own encode GEMM, the split into the
 n pieces in another; a selection scheme's pieces in one slicing program.
 
 * the pieces are bit-identical to the eager pipeline they replace
-  (slices, ``_encode_partitions``, per-piece indexing);
+  (slices, stack, the scheme's encode, per-piece indexing);
 * a warmed encode transfers nothing from the host (slice bounds are
   static, the encode matrix stays on the device);
 * every segment encode of a coded forward counts ``encodes_compiled``.
@@ -14,8 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.coded_conv import (_encode_partitions, _entry_pieces,
-                                   _piece_chains, run_segment)
+from repro.core.coded_conv import _entry_pieces, _piece_chains, run_segment
 from repro.core.schemes import commutes_elementwise, get_scheme
 from repro.core.splitting import ConvSpec, plan_segment_split
 from repro.models import cnn
@@ -51,7 +50,8 @@ def _eager_pieces(x, scheme, split):
                 for cp in _piece_chains(scheme, split)]
     parts = jnp.stack([x[..., cp.entry.a_i:cp.entry.b_i]
                        for cp in split.parts])
-    coded = _encode_partitions(scheme, parts)
+    coded = scheme.encode(parts.reshape(scheme.k, -1))
+    coded = coded.reshape((scheme.n,) + parts.shape[1:])
     return [coded[i] for i in range(scheme.n)]
 
 
